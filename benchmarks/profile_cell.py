@@ -16,11 +16,11 @@ import jax
 
 
 def profile(arch, shape, mesh_kind="pod", variant="is_fused", topn=25):
-    from repro.launch.dryrun import build_cell, mesh_ctx
+    from repro.launch.dryrun import build_cell
     from repro.launch import hlo_cost as hc
 
     mesh, fn, args, meta, _score = build_cell(arch, shape, mesh_kind, variant)
-    with mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     comps, entry = hc.parse_hlo(text)

@@ -279,6 +279,11 @@ def _prod(model: ModelConfig) -> RunConfig:
         # (loop spans, data-plane stages, collective/store counters,
         # IS-health gauges) every 10 accepted steps
         obs=ObsConfig(enabled=True),
+        # the train state is donated to the step (updated in place: what
+        # lets a full-width model fit its chip), and a donated state cannot
+        # be restored for a retry — stragglers take the monitor's shrink and
+        # escalation rungs instead
+        max_step_retries=0,
         steps=1000, ckpt_every=100)
 
 

@@ -43,6 +43,7 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.core.is_train import StepSpec, build_step, train_state_init
 from repro.data.pipeline import (DataPlane, PipelineState, SyntheticCLS,
                                  SyntheticLM)
+from repro.distributed import sharding as shd
 from repro.models.lm import LM
 from repro.optim.api import get_optimizer
 from repro.runtime.straggler import StragglerMonitor
@@ -162,12 +163,10 @@ class Experiment:
         run = build_run(arch=arch, preset=preset, overrides=flags)
         mesh = make_mesh(mesh_kind)
         if mesh is not None and "microbatches" not in flags:
-            from repro.launch.dryrun import choose_microbatches
-            dp = int(np.prod([s for s, a in zip(mesh.devices.shape,
-                                                mesh.axis_names)
-                              if a != "model"]))
+            from repro.launch.mesh import (choose_microbatches,
+                                           data_parallel_size)
             run = dataclasses.replace(run, microbatches=choose_microbatches(
-                run.model, dp, run.shape.global_batch))
+                run.model, data_parallel_size(mesh), run.shape.global_batch))
         return cls(run, source=source_kind, mesh=mesh, **kw)
 
     @classmethod
@@ -204,20 +203,30 @@ class Experiment:
         step = build_step(self.lm, self.run, self.opt, spec)
         self.step_is_flagged = spec.flagged
         extra_in = (None,) if spec.flagged else ()  # is_flag scalar
+        # the step consumes its train state: donated, XLA updates params
+        # and optimizer state in place — at full width that is the
+        # difference between fitting one chip and not. A donated state
+        # cannot be restored, so a run that may retry a straggling step
+        # (max_step_retries > 0) keeps its input state instead.
+        self.donates_state = self.run.max_step_retries == 0
+        donate = (0,) if self.donates_state else ()
+        self.state_shardings = None
         if self.mesh is not None:
-            from repro.distributed import sharding as shd
             key = jax.random.PRNGKey(self.run.seed)
             state_sds = jax.eval_shape(
                 lambda k: train_state_init(self.lm, self.opt, k), key)
-            sspecs = shd.state_specs(self.run.model, state_sds, self.mesh)
-            named = lambda t: shd.to_named(t, self.mesh)
-            self.step_fn = jax.jit(step,
-                                   in_shardings=(named(sspecs), None) + extra_in,
-                                   out_shardings=(named(sspecs), None))
+            self.state_shardings = shd.to_named(
+                shd.state_specs(self.run.model, state_sds, self.mesh),
+                self.mesh)
+            step_fn = jax.jit(step,
+                              in_shardings=(self.state_shardings, None)
+                              + extra_in,
+                              out_shardings=(self.state_shardings, None),
+                              donate_argnums=donate,
+                              compiler_options=shd.compiler_options(self.mesh))
         else:
-            # no donation here: identical scalar leaves (step/ctrl counters)
-            # can alias one buffer and double-donate on CPU
-            self.step_fn = jax.jit(step)
+            step_fn = jax.jit(step, donate_argnums=donate)
+        self.step_fn = shd.under_mesh(step_fn, self.mesh)
 
     # -- data plane ------------------------------------------------------------
     def make_plane(self) -> DataPlane:
@@ -234,8 +243,13 @@ class Experiment:
 
     # -- state ----------------------------------------------------------------
     def init_state(self):
+        """Fresh train state (laid out on the mesh's shardings, when there
+        is a mesh) and pipeline cursor."""
         key = jax.random.PRNGKey(self.run.seed)
-        return train_state_init(self.lm, self.opt, key), PipelineState()
+        init = lambda k: train_state_init(self.lm, self.opt, k)
+        if self.state_shardings is not None:
+            init = jax.jit(init, out_shardings=self.state_shardings)
+        return init(key), PipelineState()
 
     def checkpoint_payload(self, state):
         """Checkpoint payload: train state + the sampler's score memory."""

@@ -24,11 +24,12 @@ step-for-step transplant of the pre-hook loop, so metrics (loss/τ
 sequences) are bit-identical to it (``tests/test_api_loop.py`` pins this
 against a hand-rolled reference loop).
 
-Hot-path overlap (``imp.overlap_scoring``) is unchanged: while batch k's
-update runs on device, batch k+1's engine scoring is already dispatched
-(against pre-update params), and batch k-1's score feedback (device→host
-transfer + ScoreStore merges) runs on the host behind the device work.
-No synchronous ``device_get`` sits on the dispatch critical path.
+Hot-path overlap (``imp.overlap_scoring``): batch k+1's engine scoring is
+dispatched (against pre-update params) just ahead of batch k's update —
+ahead, because the update may donate those params — and batch k-1's
+score feedback (device→host transfer + ScoreStore merges) runs on the
+host behind the device work. No synchronous ``device_get`` sits on the
+dispatch critical path.
 
 Data flows through the SELECTION PLANE: the loop consumes ``BatchPlan``s
 (``repro.data.plan``) from a per-run ``DataPlane`` rather than raw
@@ -239,7 +240,7 @@ class TrainLoop:
         while i < steps:
             faults.set_step(i)
             faults.die_if(i)
-            step_state0 = state
+            step_state0, resume_at = state, i
             try:
                 batch, plan, pstate_next = self._finish_with_retry(
                     plane, handle, state)
@@ -258,6 +259,15 @@ class TrainLoop:
                 for attempt in range(run.max_step_retries + 1):
                     t0 = time.time()
                     prev_state = state
+                    if not launched_next and i + 1 < steps:
+                        # double-buffer: batch k+1's scoring against the
+                        # PRE-update params, dispatched before batch k's
+                        # update, which may donate them (scores one step
+                        # stale — selection tolerates that)
+                        handle = plane.begin(
+                            pstate_next, i + 1,
+                            params=state["params"] if overlap else None)
+                        launched_next = True
                     with self._sp_dispatch:
                         if exp.step_is_flagged:
                             state, metrics = exp.step_fn(
@@ -266,14 +276,12 @@ class TrainLoop:
                                                   jax.numpy.float32))
                         else:
                             state, metrics = exp.step_fn(state, batch)
-                    if not launched_next and i + 1 < steps:
-                        # double-buffer: launch batch k+1's scoring against
-                        # the PRE-update params while batch k's update runs
-                        # (scores one step stale — selection tolerates that)
-                        handle = plane.begin(
-                            pstate_next, i + 1,
-                            params=prev_state["params"] if overlap else None)
-                        launched_next = True
+                    if exp.donates_state:
+                        # the update consumed its input state, so there is
+                        # nothing to replay step i from: a membership change
+                        # from here on keeps this update (unrecorded) and
+                        # resumes at step i + 1
+                        step_state0, resume_at = state, i + 1
                     self.state = state
                     # previous step's score feedback overlaps device work
                     self.drain_feedback()
@@ -306,9 +314,13 @@ class TrainLoop:
                 state = step_state0
                 self.state = state
                 self._pending = None
+                if resume_at > i:
+                    i, pstate = resume_at, pstate_next
+                    self.pstate = pstate
                 plane = self._handle_membership(mc, plane, i)
-                handle = plane.begin(
-                    pstate, i, params=state["params"] if overlap else None)
+                if i < steps:
+                    handle = plane.begin(
+                        pstate, i, params=state["params"] if overlap else None)
                 continue
             if scores is not None:
                 # close the loop lazily: scores flow into the score memory

@@ -32,26 +32,12 @@ def _note_collective(name: str, payload) -> None:
         int(sum(np.asarray(v).nbytes for v in tree.values())))
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a mapped axis (inside shard_map/pmap/vmap).
-
-    ``jax.lax.axis_size`` only exists on newer jax; ``psum`` of a Python
-    literal constant-folds to the axis size as a plain int on every
-    version we support.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _mesh_axes():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.axis_names:
-            return None
-        return dict(zip(m.axis_names, m.axis_sizes))
-    except Exception:
+    """Axis sizes of the mesh in context (``jax.set_mesh``), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty:
         return None
+    return dict(zip(m.axis_names, m.axis_sizes))
 
 
 def constrain(x, *entries):
@@ -476,7 +462,7 @@ def ring_allreduce_compressed(x, axis_name, compress, decompress):
     ppermute (ring reduce). compress/decompress map f32 -> payload pytree ->
     f32. Used for the cross-pod gradient reduction where ICI/DCN bandwidth
     dominates; within-pod reductions stay full precision."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     acc = x

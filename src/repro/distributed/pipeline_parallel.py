@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.collectives import axis_size
-
 
 def pipeline_forward(stage_params, microbatches, apply_stage, axis_name="pod"):
     """Run inside shard_map over ``axis_name``.
@@ -32,7 +30,7 @@ def pipeline_forward(stage_params, microbatches, apply_stage, axis_name="pod"):
         them in, the last stage's outputs are returned (M, mb, ...).
     apply_stage: (params, x) -> y, same x/y shape for all stages.
     """
-    n_stage = axis_size(axis_name)
+    n_stage = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = microbatches.shape[0]
     ticks = n_stage + M - 1
@@ -69,18 +67,16 @@ def pipeline_forward(stage_params, microbatches, apply_stage, axis_name="pod"):
 
 def make_pipelined_stack(mesh, stage_params_stacked, apply_stage, n_micro):
     """jit-ready wrapper: shard stage params over 'pod', batch over 'data'."""
-    from jax.experimental.shard_map import shard_map
-
     def fn(params, batch):
         mb = batch.reshape((n_micro, batch.shape[0] // n_micro) + batch.shape[1:])
-        out = shard_map(
+        out = jax.shard_map(
             lambda p, m: pipeline_forward(
                 jax.tree_util.tree_map(lambda a: a[0], p), m,
                 apply_stage, "pod"),
             mesh=mesh,
             in_specs=(P("pod"), P(None, "data")),
             out_specs=P(None, "data"),
-            check_rep=False,
+            check_vma=False,
         )(params, mb)
         return out.reshape(batch.shape)
 

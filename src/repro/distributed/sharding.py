@@ -243,3 +243,27 @@ def to_named(tree, mesh: Mesh):
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), tree,
         is_leaf=lambda x: isinstance(x, P))
+
+
+def under_mesh(fn, mesh: Optional[Mesh]):
+    """``fn`` traced and dispatched with ``mesh`` in context
+    (``jax.set_mesh``), so the model's ``collectives.constrain`` intents
+    apply; ``fn`` itself when there is no mesh."""
+    if mesh is None:
+        return fn
+
+    def call(*args, **kwargs):
+        with jax.set_mesh(mesh):
+            return fn(*args, **kwargs)
+    return call
+
+
+def compiler_options(mesh: Optional[Mesh]):
+    """XLA options for a program partitioned over ``mesh``. On TPU, async
+    collective fusion fuses the all-gather of model-sharded activations
+    into the mLSTM chunk scan of an xLSTM step and then mis-schedules it:
+    the compile fails with an ``hlo_schedule`` RET_CHECK (jaxlib 0.9.0).
+    All-gathers therefore stay out of those fusions."""
+    if mesh is None or jax.default_backend() != "tpu":
+        return None
+    return {"xla_tpu_enable_async_collective_fusion_fuse_all_gather": False}

@@ -32,14 +32,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_keys.topk_keys import fmix32
+from repro.kernels.topk_keys.topk_keys import (LANES, fmix32, hash_unit,
+                                               lane_rows)
 
 
 def row_score_math(g2, mask):
-    """Per-row score from per-token stats, shared by the kernel body and
-    the oracle: the paper's ‖Ĝᵢ‖ = sqrt(Σₜ ĝ²ᵢₜ) over supervised tokens
-    (the same clamp ``LM.sample_stats`` applies)."""
-    s = jnp.sum(g2.astype(jnp.float32) * mask.astype(jnp.float32), axis=-1)
+    """Per-row score from per-token stats: the paper's ‖Ĝᵢ‖ =
+    sqrt(Σₜ ĝ²ᵢₜ) over supervised tokens (the same clamp
+    ``LM.sample_stats`` applies), as a (rows, 1) column."""
+    s = jnp.sum(g2.astype(jnp.float32) * mask.astype(jnp.float32), axis=-1,
+                keepdims=True)
     return jnp.sqrt(jnp.maximum(s, 1e-20))
 
 
@@ -65,11 +67,11 @@ def row_score_pallas(g2, mask, *, block_b=128, interpret=False):
         grid=((B + npad) // bb,),
         in_specs=[pl.BlockSpec((bb, T), lambda i: (i, 0)),
                   pl.BlockSpec((bb, T), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B + npad,), jnp.float32),
+        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B + npad, 1), jnp.float32),
         interpret=interpret,
     )(g2.astype(jnp.float32), mask.astype(jnp.float32))
-    return s[:B]
+    return s[:B, 0]
 
 
 def pool_exponentials(n, ctx_u32):
@@ -80,10 +82,7 @@ def pool_exponentials(n, ctx_u32):
     scores are still partial."""
     idx = jnp.arange(n, dtype=jnp.uint32)
     h = fmix32(idx * jnp.uint32(0x9E3779B9) ^ jnp.asarray(ctx_u32, jnp.uint32))
-    h = fmix32(h + jnp.uint32(0x6A09E667))
-    u = (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24) \
-        + jnp.float32(2.0 ** -25)
-    return -jnp.log(u)
+    return -jnp.log(hash_unit(fmix32(h + jnp.uint32(0x6A09E667))))
 
 
 def pool_keys_math(scores, idx_u32, ctx_u32, inv_total):
@@ -92,9 +91,7 @@ def pool_keys_math(scores, idx_u32, ctx_u32, inv_total):
     composition to ``selection.hash_uniform``), g = s·(1/Σs), key =
     −log(u)/g. Smaller key = more likely to win the race."""
     h = fmix32(idx_u32 * jnp.uint32(0x9E3779B9) ^ ctx_u32)
-    h = fmix32(h + jnp.uint32(0x6A09E667))
-    u = (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24) \
-        + jnp.float32(2.0 ** -25)
+    u = hash_unit(fmix32(h + jnp.uint32(0x6A09E667)))
     g = scores.astype(jnp.float32) * inv_total
     return -jnp.log(u) / jnp.maximum(g, jnp.float32(1e-20))
 
@@ -111,22 +108,22 @@ def pool_keys_pallas(scores, ctx_u32, inv_total, *, block_t=1024,
     uint32 plan context; inv_total: (1,) f32 = 1/Σs (traced — changes
     every step without recompiling) → race keys (B,) f32, +inf on pads."""
     B = scores.shape[0]
-    bt = min(block_t, B)
-    npad = -(-B // bt) * bt - B
+    rows, br = lane_rows(B, block_t)
+    npad = rows * LANES - B
     if npad:
         scores = jnp.pad(scores, (0, npad), constant_values=-1.0)
-    idx = jnp.arange(B + npad, dtype=jnp.uint32)
+    idx = jnp.arange(rows * LANES, dtype=jnp.uint32).reshape(rows, LANES)
+    tile = pl.BlockSpec((br, LANES), lambda t: (t, 0))
     r = pl.pallas_call(
         _keys_kernel,
-        grid=((B + npad) // bt,),
+        grid=(rows // br,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # ctx
             pl.BlockSpec(memory_space=pltpu.SMEM),       # 1/Σs
-            pl.BlockSpec((bt,), lambda t: (t,)),
-            pl.BlockSpec((bt,), lambda t: (t,)),
+            tile, tile,
         ],
-        out_specs=pl.BlockSpec((bt,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((B + npad,), jnp.float32),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
-    )(ctx_u32, inv_total, idx, scores)
-    return r[:B]
+    )(ctx_u32, inv_total, idx, scores.reshape(rows, LANES))
+    return r.reshape(-1)[:B]

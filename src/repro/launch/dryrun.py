@@ -73,28 +73,6 @@ def collective_bytes(hlo_text: str):
     return per_kind, sum(per_kind.values())
 
 
-def mesh_ctx(mesh):
-    """jax.set_mesh on new jax; the Mesh context manager on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def choose_microbatches(cfg, dp: int, global_batch: int) -> int:
-    """Enough gradient accumulation that activations fit 16 GB/chip."""
-    n = cfg.param_count()
-    if n > 1e11:
-        micro = 16
-    elif n > 1.5e10:
-        micro = 8
-    elif n > 4e9:
-        micro = 4
-    else:
-        micro = 1
-    local = max(global_batch // dp, 1)
-    return max(1, min(micro, local))
-
-
 # ---------------------------------------------------------------------------
 def build_cell(arch: str, shape_name: str, mesh_kind: str, variant: str):
     """Returns (mesh, jitted_fn, example_args tuple of ShapeDtypeStructs,
@@ -107,7 +85,8 @@ def build_cell(arch: str, shape_name: str, mesh_kind: str, variant: str):
     from repro.configs.base import SHAPES, ISConfig, OptimConfig, RunConfig
     from repro.core.is_train import build_train_step, build_uniform_step, train_state_init
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import (choose_microbatches, data_parallel_size,
+                                   make_production_mesh)
     from repro.launch.specs import serve_input_specs, train_input_specs
     from repro.models.lm import LM
     from repro.optim.api import get_optimizer
@@ -116,8 +95,7 @@ def build_cell(arch: str, shape_name: str, mesh_kind: str, variant: str):
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
-    dp = int(np.prod([s for s, a in zip(mesh.devices.shape, mesh.axis_names)
-                      if a != "model"]))
+    dp = data_parallel_size(mesh)
     lm = LM(cfg)
     named = lambda tree: shd.to_named(tree, mesh)
 
@@ -200,7 +178,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, variant: str,
             arch, shape_name, mesh_kind, variant)
         rec.update(meta)
         n_chips = mesh.devices.size
-        with mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(*args)
             t_lower = time.time()
             compiled = lowered.compile()

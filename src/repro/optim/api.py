@@ -23,6 +23,14 @@ class Optimizer(NamedTuple):
     update: Callable
 
 
+def _master(params):
+    """The f32 master copy — a COPY even where params are already f32, so
+    no parameter shares a buffer with its master (a donated train state
+    must not donate one buffer twice)."""
+    return jax.tree_util.tree_map(
+        lambda p: jnp.array(p, jnp.float32, copy=True), params)
+
+
 def _global_norm(tree):
     return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                         for g in jax.tree_util.tree_leaves(tree)))
@@ -63,9 +71,8 @@ def sgd(cfg, schedule=None):
     sched = schedule or constant_schedule(cfg.lr)
 
     def init(params):
-        f32 = lambda p: p.astype(jnp.float32)
         return {
-            "master": jax.tree_util.tree_map(f32, params),
+            "master": _master(params),
             "mu": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
         }
 
@@ -102,7 +109,7 @@ def adamw(cfg, schedule=None):
     def init(params):
         z = lambda p: jnp.zeros(p.shape, jnp.float32)
         return {
-            "master": jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params),
+            "master": _master(params),
             "m": jax.tree_util.tree_map(z, params),
             "v": jax.tree_util.tree_map(z, params),
         }

@@ -174,8 +174,9 @@ def remesh_shape(n_devices: int, model_degree: int):
 
 
 def remesh(n_devices: int, model_degree: int):
+    from repro.launch.mesh import build_mesh
     data, model = remesh_shape(n_devices, model_degree)
-    return jax.make_mesh((data, model), ("data", "model")), data, model
+    return build_mesh((data, model), ("data", "model")), data, model
 
 
 def rebalance_microbatches(global_batch: int, old_dp: int, old_micro: int,

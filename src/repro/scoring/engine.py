@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.distributed import sharding as shd
 
 
 class ScoreEngine:
@@ -75,14 +76,15 @@ class ScoreEngine:
             # mid-run means shape churn on the scoring path
             obs.counter("engine.jit_compiles").inc()
             if self.mesh is not None:
-                from repro.distributed import sharding as shd
                 bspecs = shd.batch_specs(
                     self.lm.cfg, jax.eval_shape(lambda: batch), self.mesh)
                 named = shd.to_named(bspecs, self.mesh)
                 # batch-only shardings: params ride on their committed layout
-                fn = jax.jit(self.fwd, in_shardings=(None, named))
+                fn = jax.jit(self.fwd, in_shardings=(None, named),
+                             compiler_options=shd.compiler_options(self.mesh))
             else:
                 fn = jax.jit(self.fwd)
+            fn = shd.under_mesh(fn, self.mesh)
             self._jitted[key] = fn
         return fn
 
@@ -141,7 +143,9 @@ class ScoreEngine:
                 return (loss_ps.astype(jnp.float32),
                         jax.lax.stop_gradient(scores.astype(jnp.float32)),
                         alive, stats)
-            fn = jax.jit(pruned)
+            fn = shd.under_mesh(jax.jit(
+                pruned, compiler_options=shd.compiler_options(self.mesh)),
+                self.mesh)
             self._jitted[key] = fn
         return fn
 

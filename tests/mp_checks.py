@@ -10,23 +10,17 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
 
-
-def _mesh_ctx(mesh):
-    """jax.set_mesh on new jax; the Mesh context manager on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from repro.launch.mesh import build_mesh  # noqa: E402
 
 
 def check_pipeline_parallel():
     """GPipe over 4 stages == sequential application."""
     from repro.distributed.pipeline_parallel import pipeline_forward
-    from jax.experimental.shard_map import shard_map
 
     n_stage, M, mb, d = 4, 6, 2, 8
-    mesh = jax.make_mesh((n_stage,), ("pod",))
+    mesh = build_mesh((n_stage,), ("pod",))
     rng = np.random.RandomState(0)
     w = jnp.asarray(rng.randn(n_stage, d, d).astype(np.float32) * 0.3)
     x = jnp.asarray(rng.randn(M, mb, d).astype(np.float32))
@@ -42,8 +36,8 @@ def check_pipeline_parallel():
         return pipeline_forward(
             wi[0], m, lambda a: jnp.tanh(a @ wi[0]), "pod")
 
-    out = jax.jit(shard_map(staged, mesh=mesh, in_specs=(P("pod"), P()),
-                            out_specs=P(), check_rep=False))(w, x)
+    out = jax.jit(jax.shard_map(staged, mesh=mesh, in_specs=(P("pod"), P()),
+                            out_specs=P(), check_vma=False))(w, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
     print("OK pipeline_parallel")
@@ -78,12 +72,12 @@ def check_sharded_is_step_matches_single_device():
     s1, m1 = jax.jit(step)(state, batch)
 
     # sharded
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh((4, 2), ("data", "model"))
     state_sds = jax.eval_shape(lambda k: train_state_init(lm, opt, k), key)
     sspecs = shd.state_specs(cfg, state_sds, mesh)
     named = lambda t: shd.to_named(t, mesh)
     state2 = train_state_init(lm, opt, key)
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(step, in_shardings=(named(sspecs), named(
             shd.batch_specs(cfg, jax.eval_shape(lambda: batch), mesh))),
             out_shardings=(named(sspecs), None))
@@ -123,9 +117,9 @@ def check_score_engine_sharded():
     }
     ref_loss, ref_sc = ScoreEngine(lm, run).score_host(params, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh((4, 2), ("data", "model"))
     eng = ScoreEngine(lm, run, mesh=mesh)
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         loss, sc = eng.score_host(params, batch)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(sc, ref_sc, rtol=1e-4, atol=1e-5)
@@ -134,9 +128,8 @@ def check_score_engine_sharded():
 
 def check_compressed_psum():
     from repro.optim.grad_compress import compressed_psum_tree, ef_init
-    from jax.experimental.shard_map import shard_map
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = build_mesh((8,), ("pod",))
     rng = np.random.RandomState(1)
     g = jnp.asarray(rng.randn(8, 64).astype(np.float32))
 
@@ -147,8 +140,8 @@ def check_compressed_psum():
                                       axis_name="pod", method="int8")
         return red["w"][None]
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pod"),),
-                            out_specs=P("pod"), check_rep=False))(g)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pod"),),
+                            out_specs=P("pod"), check_vma=False))(g)
     true = g.sum(0)
     got = np.asarray(out)[0]
     err = np.abs(got - np.asarray(true)).max() / (np.abs(np.asarray(true)).max())
@@ -174,11 +167,11 @@ def check_serve_sharded_equals_single():
     caches = lm.caches(b, 32)
     lg1, c1 = jax.jit(lm.serve_step)(params, caches, prompt)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh((4, 2), ("data", "model"))
     pspecs = shd.param_specs(cfg, jax.eval_shape(lambda: params), mesh)
     cspecs = shd.cache_specs(cfg, jax.eval_shape(lambda: caches), mesh)
     named = lambda t: shd.to_named(t, mesh)
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(lm.serve_step,
                      in_shardings=(named(pspecs), named(cspecs), None),
                      out_shardings=(None, named(cspecs)))

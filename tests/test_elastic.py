@@ -426,6 +426,34 @@ def test_loop_catches_membership_change_and_replays_step(monkeypatch):
     assert events == [(0, "timeout", (0,), 1)]
 
 
+def test_loop_keeps_donated_update_on_membership_change():
+    """With a donated train state (max_step_retries == 0) the step's input
+    is gone once it dispatches: a MembershipChange raised after that (here
+    by a step_timed hook, as straggler escalation does) keeps the update
+    and resumes at the next step instead of replaying from a deleted
+    state."""
+    from repro.api.experiment import Experiment, _resolve_run
+    exp = Experiment(_resolve_run("lm-tiny", "smoke",
+                                  {"steps": 6, "max_step_retries": 0}))
+    assert exp.donates_state
+    events = []
+
+    class Escalate(repro.Hook):
+        def on_step_timed(self, loop, step, attempt, dt):
+            if step == 2 and not events:
+                raise MembershipChange(MembershipEvent(kind="straggler",
+                                                       reason="injected"))
+            return False
+
+        def on_membership_change(self, loop, step, event, stats):
+            events.append((step, event.kind))
+    state, hist = exp.fit(steps=6, hooks=[Escalate()])
+    assert events == [(3, "straggler")]
+    assert [m["step"] for m in hist] == [0, 1, 3, 4, 5]
+    assert int(state["step"]) == 6
+    assert all(np.isfinite(m["loss"]) for m in hist)
+
+
 def test_plane_surfaces_injected_gather_fault_then_retries():
     """The data plane's surface-then-retry contract under the harness:
     the consumer sees the injected fault once, and the very next pop is

@@ -11,6 +11,7 @@ from repro.configs import get_config
 from repro.configs.base import (ISConfig, OptimConfig, RunConfig,
                                 SamplerConfig, ShapeConfig)
 from repro.data.pipeline import PipelineState, SyntheticCLS, SyntheticLM
+from repro.launch.mesh import build_mesh
 from repro.models.lm import LM
 from repro.api import Experiment as Trainer
 from repro.scoring import ScoreEngine
@@ -211,7 +212,7 @@ def test_engine_sharded_matches_single_device():
     params = lm.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, n=len(jax.devices()) * 2)
     ref_loss, ref_sc = ScoreEngine(lm, run).score_host(params, batch)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = build_mesh((len(jax.devices()),), ("data",))
     eng = ScoreEngine(lm, run, mesh=mesh)
     loss, sc = eng.score_host(params, batch)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-5, atol=1e-5)
