@@ -24,7 +24,6 @@ ARTIFACTS = {
     "pipeline": "BENCH_pipeline",
     "fused": "BENCH_fused",
     "selection": "BENCH_selection",
-    "obs": "BENCH_obs",
     "scoring_overlap": "BENCH_scoring",
     "score_prune": "BENCH_prune",
 }
@@ -32,9 +31,9 @@ ARTIFACTS = {
 
 def main() -> None:
     from benchmarks import paper_figures as pf
-    from benchmarks import (data_plane, fused_presample, obs_overhead,
-                            roofline, sampler_compare, score_prune,
-                            scoring_overhead, selection_scale, svrg_compare)
+    from benchmarks import (data_plane, fused_presample, roofline,
+                            sampler_compare, score_prune, scoring_overhead,
+                            selection_scale, svrg_compare)
     from benchmarks.common import ART
 
     suites = {
@@ -42,7 +41,6 @@ def main() -> None:
         "pipeline": data_plane.bench_data_plane,
         "fused": fused_presample.bench_fused_presample,
         "selection": selection_scale.bench_selection_scale,
-        "obs": obs_overhead.bench_obs_overhead,
         "fig1": pf.fig1_variance_reduction,
         "fig2": pf.fig2_correlation,
         "fig3": pf.fig3_convergence,

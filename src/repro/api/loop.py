@@ -81,7 +81,8 @@ class TrainLoop:
         # telemetry (inert unless run.obs enables the registry)
         self._sp_dispatch = obs.span("loop.dispatch")
         self._sp_drain = obs.span("loop.drain_feedback")
-        self._sp_retry = obs.span("loop.retry")
+        self._sp_sync = obs.span("loop.sync")
+        self._sp_emit = {e: obs.span(f"loop.emit.{e}") for e in EVENTS}
         self._h_step = obs.histogram("loop.step_s")
         self._c_steps = obs.counter("loop.steps")
         self._c_retries = obs.counter("loop.retries")
@@ -95,26 +96,29 @@ class TrainLoop:
         the failure is counted (``loop.hook_errors``) and reported once
         per hook class. The one exception is ``step_timed``
         (``_vote_retry``): its return value is loop SEMANTICS (retry
-        votes), so it stays un-guarded by design."""
-        for h in self.hooks:
-            try:
-                getattr(h, "on_" + event)(self, *args)
-            except Exception as e:
-                self._c_hook_errors.inc()
-                cls = type(h)
-                if cls not in self._failed_hooks:
-                    self._failed_hooks.add(cls)
-                    print(f"[repro] hook {cls.__name__}.on_{event} raised "
-                          f"{type(e).__name__}: {e} — hook errors are "
-                          f"isolated; reporting this hook class once",
-                          file=sys.stderr, flush=True)
+        votes), so it stays un-guarded by design. The hooks' host time is
+        the span ``loop.emit.<event>``."""
+        with self._sp_emit[event]:
+            for h in self.hooks:
+                try:
+                    getattr(h, "on_" + event)(self, *args)
+                except Exception as e:
+                    self._c_hook_errors.inc()
+                    cls = type(h)
+                    if cls not in self._failed_hooks:
+                        self._failed_hooks.add(cls)
+                        print(f"[repro] hook {cls.__name__}.on_{event} "
+                              f"raised {type(e).__name__}: {e} — hook "
+                              f"errors are isolated; reporting this hook "
+                              f"class once", file=sys.stderr, flush=True)
 
     def _vote_retry(self, step, attempt, dt) -> bool:
         # list, not generator: every hook observes every attempt.
         # Deliberately NOT exception-isolated — retry votes are control
         # flow, not observation (see emit()).
-        local = any([h.on_step_timed(self, step, attempt, dt)
-                     for h in self.hooks])
+        with self._sp_emit["step_timed"]:
+            local = any([h.on_step_timed(self, step, attempt, dt)
+                         for h in self.hooks])
         # The local vote is derived from this host's wall-clock
         # (StragglerHook), so acting on it alone would re-dispatch the
         # jitted step — and its collectives — on this host only: the
@@ -238,107 +242,116 @@ class TrainLoop:
             pstate, start, params=state["params"] if overlap else None)
         i = start
         while i < steps:
-            faults.set_step(i)
-            faults.die_if(i)
-            step_state0, resume_at = state, i
-            try:
-                batch, plan, pstate_next = self._finish_with_retry(
-                    plane, handle, state)
-                # the train path's H2D: fused presample hands device arrays
-                # through (asarray is a no-op) and the counter stays at
-                # zero — the per-step transfer claim the fused benchmark
-                # checks
-                h2d = sum(np.asarray(v).nbytes for v in batch.values()
-                          if not isinstance(v, jax.Array))
-                if h2d:
-                    self._c_h2d.inc(h2d)
-                batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-                self.emit("step_start", i, batch, plan)
-                launched_next = False
-                dt_total = 0.0
-                for attempt in range(run.max_step_retries + 1):
-                    t0 = time.time()
-                    prev_state = state
-                    if not launched_next and i + 1 < steps:
-                        # double-buffer: batch k+1's scoring against the
-                        # PRE-update params, dispatched before batch k's
-                        # update, which may donate them (scores one step
-                        # stale — selection tolerates that)
-                        handle = plane.begin(
-                            pstate_next, i + 1,
-                            params=state["params"] if overlap else None)
-                        launched_next = True
-                    with self._sp_dispatch:
-                        if exp.step_is_flagged:
-                            state, metrics = exp.step_fn(
-                                state, batch,
-                                jax.numpy.asarray(plan["is_flag"],
-                                                  jax.numpy.float32))
-                        else:
-                            state, metrics = exp.step_fn(state, batch)
-                    if exp.donates_state:
-                        # the update consumed its input state, so there is
-                        # nothing to replay step i from: a membership change
-                        # from here on keeps this update (unrecorded) and
-                        # resumes at step i + 1
-                        step_state0, resume_at = state, i + 1
-                    self.state = state
-                    # previous step's score feedback overlaps device work
-                    self.drain_feedback()
-                    scores = metrics.pop("sample_scores", None)
-                    metrics = {k: float(v) for k, v in metrics.items()}
-                    dt = time.time() - t0 + faults.slow_penalty(i)
-                    dt_total += dt
-                    if not self._vote_retry(i, attempt, dt) \
-                            or attempt == run.max_step_retries:
-                        # accepted — or retries exhausted, in which case
-                        # the (already computed, merely slow) update is
-                        # kept: the batch is RETRIED under a skip and never
-                        # dropped
-                        break
-                    # straggler escalation: drop this attempt's result
-                    # (params AND score feedback) and RETRY THE SAME BATCH
-                    # — bounded by max_step_retries; the monitor's own skip
-                    # budget forces a sync once exhausted
-                    state = prev_state
-                    self.state = state
-                    self._c_retries.inc()
-                    with self._sp_retry:
+            # one step marker per iteration: the profiler's step, and the
+            # thread the benchmark reads as the loop's
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                faults.set_step(i)
+                faults.die_if(i)
+                step_state0, resume_at = state, i
+                try:
+                    batch, plan, pstate_next = self._finish_with_retry(
+                        plane, handle, state)
+                    # the train path's H2D: fused presample hands device arrays
+                    # through (asarray is a no-op) and the counter stays at
+                    # zero — the per-step transfer claim the fused benchmark
+                    # checks
+                    h2d = sum(np.asarray(v).nbytes for v in batch.values()
+                              if not isinstance(v, jax.Array))
+                    if h2d:
+                        self._c_h2d.inc(h2d)
+                    batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+                    self.emit("step_start", i, batch, plan)
+                    launched_next = False
+                    dt_total = 0.0
+                    for attempt in range(run.max_step_retries + 1):
+                        t0 = time.time()
+                        prev_state = state
+                        if not launched_next and i + 1 < steps:
+                            # double-buffer: batch k+1's scoring against the
+                            # PRE-update params, dispatched before batch k's
+                            # update, which may donate them (scores one step
+                            # stale — selection tolerates that)
+                            handle = plane.begin(
+                                pstate_next, i + 1,
+                                params=state["params"] if overlap else None)
+                            launched_next = True
+                        with self._sp_dispatch:
+                            if exp.step_is_flagged:
+                                state, metrics = exp.step_fn(
+                                    state, batch,
+                                    jax.numpy.asarray(plan["is_flag"],
+                                                      jax.numpy.float32))
+                            else:
+                                state, metrics = exp.step_fn(state, batch)
+                        if exp.donates_state:
+                            # the update consumed its input state, so there
+                            # is nothing to replay step i from: a membership
+                            # change from here on keeps this update
+                            # (unrecorded) and resumes at step i + 1
+                            step_state0, resume_at = state, i + 1
+                        self.state = state
+                        # previous step's score feedback overlaps device work
+                        self.drain_feedback()
+                        # the host blocks here until the update is done, then
+                        # reads each scalar
+                        with self._sp_sync:
+                            scores = metrics.pop("sample_scores", None)
+                            metrics = {k: float(v)
+                                       for k, v in metrics.items()}
+                        dt = time.time() - t0 + faults.slow_penalty(i)
+                        dt_total += dt
+                        if not self._vote_retry(i, attempt, dt) \
+                                or attempt == run.max_step_retries:
+                            # accepted — or retries exhausted, in which
+                            # case the (already computed, merely slow)
+                            # update is kept: the batch is RETRIED under a
+                            # skip and never dropped
+                            break
+                        # straggler escalation: drop this attempt's
+                        # result (params AND score feedback) and RETRY THE
+                        # SAME BATCH — bounded by max_step_retries; the
+                        # monitor's own skip budget forces a sync once
+                        # exhausted
+                        state = prev_state
+                        self.state = state
+                        self._c_retries.inc()
                         self.emit("retry", i, attempt, dt)
-            except MembershipChange as mc:
-                # membership is a loop event, not a crash: drop this step's
-                # partial work (params AND the previous step's undrained
-                # feedback — its row slicing belonged to the old
-                # membership), reshard, and replay step i from the same
-                # plan cursor under the survivors.
-                state = step_state0
-                self.state = state
-                self._pending = None
-                if resume_at > i:
-                    i, pstate = resume_at, pstate_next
-                    self.pstate = pstate
-                plane = self._handle_membership(mc, plane, i)
-                if i < steps:
-                    handle = plane.begin(
-                        pstate, i, params=state["params"] if overlap else None)
-                continue
-            if scores is not None:
-                # close the loop lazily: scores flow into the score memory
-                # behind the NEXT step's device work (drain_feedback)
-                self._pending = (i, plan, scores)
-            pstate = pstate_next
-            self.pstate = pstate
-            # retried steps used to mis-report timing: `dt` is the LAST
-            # attempt only. Carry the attempt count and the cumulative
-            # wall time so consumers can tell a clean 50 ms step from a
-            # 3-attempt 150 ms one.
-            metrics.update(step=i, dt=dt, attempts=attempt + 1,
-                           dt_total=dt_total, **exp.sampler.stats())
-            self._h_step.observe(dt)
-            self._c_steps.inc()
-            self.steps_run += 1
-            self.emit("step_end", i, metrics)
-            i += 1
+                except MembershipChange as mc:
+                    # membership is a loop event, not a crash: drop this
+                    # step's partial work (params AND the previous step's
+                    # undrained feedback — its row slicing belonged to the
+                    # old membership), reshard, and replay step i from the
+                    # same plan cursor under the survivors.
+                    state = step_state0
+                    self.state = state
+                    self._pending = None
+                    if resume_at > i:
+                        i, pstate = resume_at, pstate_next
+                        self.pstate = pstate
+                    plane = self._handle_membership(mc, plane, i)
+                    if i < steps:
+                        handle = plane.begin(
+                            pstate, i,
+                            params=state["params"] if overlap else None)
+                    continue
+                if scores is not None:
+                    # close the loop lazily: scores flow into the score
+                    # memory behind the NEXT step's device work
+                    # (drain_feedback)
+                    self._pending = (i, plan, scores)
+                pstate = pstate_next
+                self.pstate = pstate
+                # retried steps used to mis-report timing: `dt` is the LAST
+                # attempt only. Carry the attempt count and the cumulative
+                # wall time so consumers can tell a clean 50 ms step from a
+                # 3-attempt 150 ms one.
+                metrics.update(step=i, dt=dt, attempts=attempt + 1,
+                               dt_total=dt_total, **exp.sampler.stats())
+                self._h_step.observe(dt)
+                self._c_steps.inc()
+                self.steps_run += 1
+                self.emit("step_end", i, metrics)
+                i += 1
         plane.stop()
         self.drain_feedback()
         self.emit("loop_end", state, history)

@@ -22,7 +22,10 @@ Instrument kinds:
 * ``Span`` — a monotonic wall-clock timer (``time.perf_counter``) over a
   ``with`` block, recording seconds into its histogram. Spans nest
   (per-thread stack, exception-safe); worker threads time their own
-  stages concurrently without interference.
+  stages concurrently without interference. While a JAX profiler
+  session collects, a span is also a ``jax.profiler.TraceAnnotation`` of
+  its name, so it lands in the trace on the device's clock, on the
+  thread that ran it — whether or not the registry is enabled.
 
 Thread-safety: get-or-create goes through one registry lock; record-time
 mutation relies on per-instrument locks only where a read-modify-write
@@ -130,13 +133,36 @@ class Histogram:
                                 for e, n in sorted(self.buckets.items())}}
 
 
+def _profiling() -> bool:
+    """True while a JAX profiler session collects. The first call imports
+    the profiler (``from repro import obs`` stays free of jax) and rebinds
+    this name to ``TraceMe.is_enabled`` itself, so each later check is one
+    C call."""
+    global _profiling, _Annotation
+    import jax.profiler
+    from jax._src.lib import _profiler
+    _Annotation = jax.profiler.TraceAnnotation
+    _profiling = _profiler.TraceMe.is_enabled
+    return _profiling()
+
+
+_Annotation = None          # jax.profiler.TraceAnnotation, bound lazily
+
+
 class Span(Histogram):
     """Monotonic wall-clock timer over a ``with`` block.
 
-    Reusable and nest-safe: each thread keeps its own stack of start
-    times, so ``with obs.span("a"): ...`` can nest inside itself (retry
-    loops) and run concurrently on pipeline worker threads. Seconds are
+    Reusable and nest-safe: each thread keeps its own stack of entries,
+    so ``with obs.span("a"): ...`` can nest inside itself (retry loops)
+    and run concurrently on pipeline worker threads. Seconds are
     recorded into the inherited histogram.
+
+    An entry is ``(start time or None, profiler annotation or None)``:
+    the clock is read only while the registry is enabled, the annotation
+    opened only while a profiler session collects. Entries stay paired
+    when either switch flips mid-span: an enter pushes whenever a
+    recorded entry of this span is already open on the thread (``None``
+    when there is nothing to record), so every exit pops its own.
     """
 
     kind = "span"
@@ -146,24 +172,36 @@ class Span(Histogram):
         self._local = threading.local()
 
     def __enter__(self):
-        if self._reg.enabled:
-            stack = getattr(self._local, "stack", None)
+        on = self._reg.enabled
+        ann = None
+        if _profiling():
+            ann = _Annotation(self.name)
+            ann.__enter__()
+        stack = getattr(self._local, "stack", None)
+        if on or ann is not None:
             if stack is None:
                 stack = self._local.stack = []
             # repro-lint: disable=RL001 -- span timing is telemetry; the
             # measured duration is written to sinks, never into plan bytes
-            stack.append(time.perf_counter())
+            stack.append((time.perf_counter() if on else None, ann))
+        elif stack:
+            stack.append(None)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        # guard the pop: the registry may have been enabled mid-span
-        # (start missing) or disabled (drop the measurement silently)
+        # an empty stack: nothing was recorded at enter (the registry and
+        # the profiler were both off), whatever they are now
         stack = getattr(self._local, "stack", None)
         if stack:
-            t0 = stack.pop()
-            # repro-lint: disable=RL001 -- same: span duration goes to
-            # telemetry sinks only, never into plan bytes
-            self.observe(time.perf_counter() - t0)
+            entry = stack.pop()
+            if entry is not None:
+                t0, ann = entry
+                if t0 is not None:
+                    # repro-lint: disable=RL001 -- same: span duration goes
+                    # to telemetry sinks only, never into plan bytes
+                    self.observe(time.perf_counter() - t0)
+                if ann is not None:
+                    ann.__exit__(exc_type, exc, tb)
         return False
 
 

@@ -91,15 +91,20 @@ SCHEMA = (
     ("loop.dispatch", "span", "step dispatch (device work is async)"),
     ("loop.drain_feedback", "span",
      "score feedback D2H + ScoreStore merge, off the dispatch path"),
+    ("loop.emit.*", "span",
+     "observer hooks' host time, one span per loop event (`step_end`, "
+     "`step_start`, ...; `step_timed` covers the retry vote)"),
     ("loop.h2d_bytes", "counter",
      "train-batch bytes uploaded by the loop (0 on the fused presample "
      "path — its batches arrive device-resident)"),
     ("loop.hook_errors", "counter",
      "exceptions raised by observer hooks (isolated, counted)"),
     ("loop.retries", "counter", "straggler retry attempts"),
-    ("loop.retry", "span", "retry bookkeeping"),
     ("loop.step_s", "histogram", "accepted-step wall time"),
     ("loop.steps", "counter", "accepted steps"),
+    ("loop.sync", "span",
+     "the host blocks on the step's update, then reads each step metric "
+     "device->host"),
     ("plane.batches", "counter", "batches produced by the data plane"),
     ("plane.credit_stalls", "counter",
      "worker stalls waiting for queue credit"),
@@ -129,6 +134,12 @@ SCHEMA = (
     ("sampler.d2h_bytes", "counter",
      "score bytes pulled device->host (the ONE pool-sized transfer "
      "either presample path makes)"),
+    ("sampler.pull_scores", "span",
+     "presample score vector device->host (blocks on the score pass) "
+     "plus the prune stats"),
+    ("sampler.select", "span",
+     "host selection from the pulled scores: the race, the ScoreStore "
+     "update and the tau EMA"),
     ("sampler.selection_impl.*", "counter",
      "resolved selection impl, recorded once per run (`gather` / "
      "`sharded` — how `auto` resolved)"),

@@ -294,6 +294,8 @@ class HostPresampleSampler(Sampler):
         # presample_race_select_raw); "off" is the PR-7 byte-exact path
         self.prune = (getattr(self.icfg, "score_prune", "off")
                       == "conservative")
+        self._sp_pull = obs.span("sampler.pull_scores")
+        self._sp_select = obs.span("sampler.select")
 
     @property
     def active(self) -> bool:
@@ -341,7 +343,8 @@ class HostPresampleSampler(Sampler):
         # every host scored only its candidate slice; the gathered vector
         # (identity single-host) is what makes selection globally agreed
         scores = self._gather_rows(self._pull_scores(fut), cplan.n_rows)
-        plan = self._select_plan(cplan, scores, handle["step"])
+        with self._sp_select:
+            plan = self._select_plan(cplan, scores, handle["step"])
         batch = self._materialize(handle, cplan, plan)
         return batch, plan, handle["nxt"]
 
@@ -349,11 +352,12 @@ class HostPresampleSampler(Sampler):
         """Block on the score pass and bring THIS host's (B/H,) score
         shard down — the one pool-sized D2H transfer either presample
         path makes (the counter is the fused benchmark's evidence)."""
-        local = np.asarray(jax.device_get(fut[1]), np.float32)
-        obs.counter("sampler.d2h_bytes").inc(local.nbytes)
-        if len(fut) > 3:      # pruned pass: (loss, scores, alive, stats)
-            self._record_prune_stats(fut[3])
-        return local
+        with self._sp_pull:
+            local = np.asarray(jax.device_get(fut[1]), np.float32)
+            obs.counter("sampler.d2h_bytes").inc(local.nbytes)
+            if len(fut) > 3:      # pruned pass: (loss, scores, alive, stats)
+                self._record_prune_stats(fut[3])
+            return local
 
     def _record_prune_stats(self, stats) -> None:
         """The pruned pass's flop receipt: [rows_killed, tiles_skipped,
