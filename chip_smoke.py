@@ -46,6 +46,10 @@ STEPS = 6
 # and the chip's transcendental functions; printed with each check
 SCORE_RTOL = 1e-3        # survivor scores / per-row loss, kernel vs oracle
 KEY_RTOL = 1e-5          # race keys, kernel vs oracle (same hash, f32 tail)
+# sLSTM kernel vs lax.scan, relative L2 per output: both round the
+# recurrent products' operands to bf16, and each is ~5e-3 off exact f32
+# products in the gradients; the kernel sits ~6e-4 from the scan
+SLSTM_RTOL = 2e-3
 # four chips: the sharded step rounds bf16 partial sums that one device
 # accumulates in f32 (gradients and updates are held to a yardstick
 # measured in the run, see sharded_vs_single)
@@ -210,7 +214,45 @@ def kernel_parity():
                  f"{'equal' if same else 'DIFFERS'}, key max rel err "
                  f"{err_k:.3e} (tol {KEY_RTOL:g}): {'PASS' if ok else 'FAIL'}")
     check(ok, lines[-1])
+    lines.append(slstm_parity())
     return lines
+
+
+def slstm_parity():
+    """The sLSTM recurrence kernel (``slstm_scan``) against its
+    ``lax.scan`` oracle at xlstm-350m's width over the trained rows:
+    outputs, final state, and one VJP (g_in, R, initial state). R at the
+    model's init scale. Returns the printed line; raises on a miss."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.slstm.ops import slstm_scan
+    from repro.kernels.slstm.ref import slstm_scan_ref
+
+    b, s, d, nh = BATCH, SEQ, 1024, 4
+    hd = d // nh
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
+    g = jax.random.normal(ks[0], (b, s, 4 * d))
+    r = 0.05 * jax.random.truncated_normal(ks[1], -2.0, 2.0,
+                                           (nh, hd, 4 * hd))
+    state0 = (jnp.zeros((b, d)), jnp.ones((b, d)), jnp.zeros((b, d)))
+    ct = jax.random.normal(ks[2], (b, s, d))
+
+    def run(scan):
+        out, vjp = jax.vjp(scan, g, r, state0)
+        return out, vjp((ct, jax.tree.map(jnp.ones_like, out[1])))
+    got = jax.jit(lambda: run(slstm_scan))()
+    want = jax.jit(lambda: run(slstm_scan_ref))()
+    names = ["hs", "c", "n", "h", "dg_in", "dR", "dc0", "dn0", "dh0"]
+    errs = {k: float(jnp.linalg.norm(x - y) / jnp.linalg.norm(y))
+            for k, x, y in zip(names, jax.tree.leaves(got),
+                               jax.tree.leaves(want))}
+    ok = all(e <= SLSTM_RTOL for e in errs.values())
+    line = (f"parity slstm (slstm_scan vs slstm_scan_ref, forward and VJP) "
+            f"b={b} s={s} d={d} heads={nh}: rel L2 err "
+            + ", ".join(f"{k} {e:.3e}" for k, e in errs.items())
+            + f" (tol {SLSTM_RTOL:g}): {'PASS' if ok else 'FAIL'}")
+    check(ok, line)
+    return line
 
 
 def one_chip(launch, jax):

@@ -9,13 +9,18 @@ rides along as the last value channel. Gates use sigmoid activations
 DESIGN.md §8).
 
 sLSTM keeps per-head scalar state with block-diagonal recurrent weights and
-is inherently sequential → ``lax.scan`` over time.
+is inherently sequential: a Pallas kernel over time on TPU
+(``repro.kernels.slstm``), ``lax.scan`` elsewhere.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels.slstm.ref import slstm_scan_ref
 from repro.models.common import dense_init, dtype_of, rmsnorm, split_key
 from repro.models.ssm import _mamba_conv, chunked_gla, gla_step
 
@@ -134,65 +139,64 @@ def init_slstm(key, cfg):
     }
 
 
+def _slstm_state0(b, d):
+    return (jnp.zeros((b, d), jnp.float32), jnp.ones((b, d), jnp.float32),
+            jnp.zeros((b, d), jnp.float32))
+
+
 def slstm_cache_init(cfg, batch, dtype):
-    d = cfg.d_model
-    return {
-        "c": jnp.zeros((batch, d), jnp.float32),
-        "n": jnp.ones((batch, d), jnp.float32),
-        "h": jnp.zeros((batch, d), jnp.float32),
-    }
+    return dict(zip("cnh", _slstm_state0(batch, cfg.d_model)))
 
 
-def _slstm_step(params, cfg, state, g_in):
-    """state: (c, n, h) each (b, d) f32; g_in: (b, 4d) input-side gate preacts."""
-    nh = cfg.n_heads
-    d = cfg.d_model
-    hd = d // nh
-    c, n, h = state
-    hh = h.reshape(-1, nh, hd)
-    rec = jnp.einsum("bhd,hdf->bhf", hh, params["r_gates"]).reshape(-1, 4 * d)
-    # interleave per-head gate slices: both g_in and rec are laid out (4, nh, hd)
-    g = g_in + rec
-    i_r, f_r, z_r, o_r = jnp.split(g, 4, axis=-1)
-    i_g = jnp.exp(jnp.minimum(i_r, 10.0))                  # exponential input gate (clipped)
-    f_g = jax.nn.sigmoid(f_r)
-    c = f_g * c + i_g * jnp.tanh(z_r)
-    n = f_g * n + i_g
-    h = jax.nn.sigmoid(o_r) * c / jnp.maximum(n, 1.0)
-    return (c, n, h), h
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def _use_kernel(s, hd):
+    """The time recurrence runs as the Pallas kernel on TPU for more than
+    one step at lane-aligned head widths; a decode step, and every other
+    backend, keep the reference scan."""
+    return s > 1 and hd % 128 == 0 and _on_tpu()
+
+
+def _per_shard(fn, b):
+    """``fn`` (rows independent, R whole) run on each device's rows of the
+    mesh in context (``jax.set_mesh``): the kernel's custom call has no
+    partitioning rule, and GSPMD would give every device the whole batch.
+    Rows split over the data-parallel axes that divide ``b``; every other
+    axis holds a copy. Without a mesh, ``fn`` itself."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    dp = tuple(a for a in ("pod", "data") if a in sizes)
+    rows = P(dp) if dp and b % math.prod(sizes[a] for a in dp) == 0 else P()
+    # a pallas_call's outputs carry no varying-axes type to check
+    return jax.shard_map(fn, in_specs=(rows, P(), (rows,) * 3),
+                         out_specs=(rows, (rows,) * 3), check_vma=False)
+
+
+def _slstm_scan(params, g_in, state):
+    r = params["r_gates"]
+    if not _use_kernel(g_in.shape[1], r.shape[1]):
+        return slstm_scan_ref(g_in, r, state)
+    from repro.kernels.slstm.ops import slstm_scan
+    return _per_shard(slstm_scan, g_in.shape[0])(g_in, r, state)
 
 
 def apply_slstm(params, x, *, cfg, cache=None):
     b, s, d = x.shape
     g_in = x.astype(jnp.float32) @ params["w_gates"]        # (b,s,4d)
-    if cache is None:
-        state = (jnp.zeros((b, d), jnp.float32), jnp.ones((b, d), jnp.float32),
-                 jnp.zeros((b, d), jnp.float32))
-    else:
-        state = (cache["c"], cache["n"], cache["h"])
-
-    def step(st, gt):
-        return _slstm_step(params, cfg, st, gt)
-
-    # NB: unroll=8 was tried to amortise the recurrent-weight read (§Perf
-    # C1) — it REGRESSED the measured memory term by 8% (the unrolled
-    # bodies defeat the in-place scan-carry optimisation); reverted.
-    state, hs = jax.lax.scan(step, state, g_in.transpose(1, 0, 2))
-    y = hs.transpose(1, 0, 2).astype(x.dtype)               # (b,s,d)
-    y = rmsnorm(params["out_norm"], y, cfg.norm_eps)
+    state = _slstm_state0(b, d) if cache is None else \
+        (cache["c"], cache["n"], cache["h"])
+    hs, state = _slstm_scan(params, g_in, state)
+    y = rmsnorm(params["out_norm"], hs.astype(x.dtype), cfg.norm_eps)
     out = y @ params["w_out"]
-    new_cache = None if cache is None else {"c": state[0], "n": state[1], "h": state[2]}
-    return out, new_cache
+    return out, None if cache is None else dict(zip("cnh", state))
 
 
 def prefill_slstm_cache(params, x, *, cfg):
     b, s, d = x.shape
     g_in = x.astype(jnp.float32) @ params["w_gates"]
-    state = (jnp.zeros((b, d), jnp.float32), jnp.ones((b, d), jnp.float32),
-             jnp.zeros((b, d), jnp.float32))
-
-    def step(st, gt):
-        return _slstm_step(params, cfg, st, gt)
-
-    state, _ = jax.lax.scan(step, state, g_in.transpose(1, 0, 2))
-    return {"c": state[0], "n": state[1], "h": state[2]}
+    _, state = _slstm_scan(params, g_in, _slstm_state0(b, d))
+    return dict(zip("cnh", state))

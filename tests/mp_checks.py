@@ -126,6 +126,48 @@ def check_score_engine_sharded():
     print("OK score_engine_sharded")
 
 
+def check_slstm_kernel_sharded():
+    """The sLSTM block under a (4,2) mesh with the recurrence on the
+    kernel (interpret mode) == the scan on one device, loss and every
+    parameter's gradient; the kernel sees each data shard's rows."""
+    from repro.configs.base import SLSTM, ModelConfig, Segment
+    from repro.distributed import sharding as shd
+    from repro.kernels.slstm import ops
+    from repro.models import xlstm
+
+    cfg = ModelConfig(name="slstm-t", family="ssm", d_model=256, n_heads=2,
+                      n_kv_heads=2, d_ff=0, vocab_size=64,
+                      segments=(Segment((SLSTM,), 1),), dtype="float32")
+    params = xlstm.init_slstm(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 12, cfg.d_model))
+    y = jax.random.normal(jax.random.PRNGKey(2), (8, 12, cfg.d_model))
+
+    def loss(p, x):
+        out, _ = xlstm.apply_slstm(p, x, cfg=cfg)
+        return jnp.mean((out - y) ** 2)
+
+    grad = jax.jit(jax.value_and_grad(loss))
+    ref_loss, ref_grads = grad(params, x)
+
+    mesh = build_mesh((4, 2), ("data", "model"))
+    xlstm._on_tpu = lambda: True
+    rows = []
+    scan = ops.slstm_scan
+    ops.slstm_scan = lambda g, *a: rows.append(g.shape[0]) or scan(g, *a)
+    specs = {"w_gates": P(None, "model"), "r_gates": P(),
+             "w_out": P("model", None), "out_norm": {"scale": P()}}
+    with jax.set_mesh(mesh):
+        p = jax.device_put(params, shd.to_named(specs, mesh))
+        xs = jax.device_put(x, jax.NamedSharding(mesh, P("data")))
+        k_loss, k_grads = grad(p, xs)
+    assert rows and set(rows) == {2}, rows
+    np.testing.assert_allclose(float(k_loss), float(ref_loss), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(k_grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    print("OK slstm_kernel_sharded")
+
+
 def check_compressed_psum():
     from repro.optim.grad_compress import compressed_psum_tree, ef_init
 

@@ -30,6 +30,7 @@ def _run(check):
     "score_engine_sharded",
     "compressed_psum",
     "serve_sharded_equals_single",
+    "slstm_kernel_sharded",
 ])
 def test_multidevice(check):
     _run(check)

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a described
 TPU v5e, with ``interpret=False`` and at real widths (xLSTM-350M's vocab,
-seq 1024, pools of 12 and 768 rows).
+seq 1024, pools of 12 and 768 rows; its sLSTM recurrence at seq 2048 over
+the 4 trained and 12 scored rows).
 
 Interpret mode accepts block shapes and casts that the chip's compiler
 refuses, so the CPU parity tests cannot see these failures. The TPU
@@ -24,13 +25,16 @@ from repro.kernels.ce_score.ce_score import (ce_score_block_pallas,
 from repro.kernels.fused_presample.fused_presample import (pool_keys_pallas,
                                                            row_score_pallas)
 from repro.kernels.fused_presample.ops import _pruned_pool_score
+from repro.kernels.slstm.slstm import (padded_rows, slstm_bwd_pallas,
+                                      slstm_fwd_pallas)
 from repro.kernels.topk_keys.topk_keys import race_keys_pallas
 
 V, T, CHUNK = 50304, 1024, 128
+D, NH, S = 1024, 4, 2048        # xlstm-350m's sLSTM: d_model, heads, seq
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     import os
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
@@ -42,7 +46,12 @@ def one_chip():
     # a compile for a described chip cannot be read back from the
     # persistent cache without the chip; keep it out of the cache
     jax.config.update("jax_enable_compilation_cache", False)
-    return SingleDeviceSharding(topo.devices[0])
+    return topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -97,3 +106,118 @@ def test_race_keys_compiles(one_chip):
              ((100_000,), jnp.float32), ((100_000,), jnp.float32),
              ((100_000,), jnp.uint32), ((1,), jnp.uint32),
              ((4,), jnp.float32))
+
+
+def _slstm_state(b):
+    return (((b, D), jnp.float32),) * 3
+
+
+@pytest.mark.parametrize("b", [4, 12])
+@pytest.mark.parametrize("save", [False, True])
+def test_slstm_fwd_compiles(one_chip, b, save):
+    _compile(functools.partial(slstm_fwd_pallas, save=save, interpret=False),
+             one_chip, ((b, S, 4 * D), jnp.float32),
+             ((NH, D // NH, 4 * D // NH), jnp.bfloat16), *_slstm_state(b))
+
+
+@pytest.mark.parametrize("b", [4, 12])
+def test_slstm_bwd_compiles(one_chip, b):
+    bp = padded_rows(b)       # the forward's residuals are row-padded
+    _compile(functools.partial(slstm_bwd_pallas, interpret=False), one_chip,
+             ((b, S, D), jnp.float32), ((S, bp, 4 * D), jnp.float32),
+             ((S, bp, D), jnp.float32), ((S, bp, D), jnp.float32),
+             ((NH, D // NH, 4 * D // NH), jnp.bfloat16), *_slstm_state(b))
+
+
+def test_xlstm_programs_run_the_slstm_kernel(one_chip, monkeypatch):
+    """The update (``jit_host_step``: forward and backward) and the pool
+    scoring (``jit_pruned``: forward) of xlstm-350m at the chip
+    benchmark's shapes, lowered for the described chip, run the sLSTM
+    recurrence as the kernel. The ops ask the default backend (the CPU
+    here) which branch to take; steer them to the chip's."""
+    from repro.api import Experiment
+    from repro.api.config import build_run
+    from repro.core.is_train import train_state_init
+    from repro.kernels.ce_score import ops as ce_ops
+    from repro.kernels.fused_presample import ops as fp_ops
+    from repro.kernels.slstm import ops as slstm_ops
+    from repro.kernels.topk_keys import ops as tk_ops
+    from repro.models import xlstm
+    for mod in (ce_ops, fp_ops, slstm_ops, tk_ops, xlstm):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    b, ratio = 4, 3
+    exp = Experiment(build_run(arch="xlstm-350m", preset="prod", overrides={
+        "shape.seq_len": S, "shape.global_batch": b,
+        "imp.presample_ratio": ratio, "obs.sink": "none"}))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+    state = on_chip(jax.eval_shape(
+        lambda k: train_state_init(exp.lm, exp.opt, k),
+        jax.random.PRNGKey(0)))
+    rows = lambda n: {"tokens": jax.ShapeDtypeStruct((n, S), jnp.int32),
+                      "labels": jax.ShapeDtypeStruct((n, S), jnp.int32)}
+    batch = on_chip(dict(rows(b),
+                         weights=jax.ShapeDtypeStruct((b,), jnp.float32)))
+    args = (state, batch) + ((on_chip(jax.ShapeDtypeStruct((), jnp.float32)),)
+                             if exp.step_is_flagged else ())
+    step = exp.step_fn.lower(*args).as_text()
+    pool = on_chip(rows(b * ratio))
+    pruned = exp.engine._fn_pruned(pool, b).lower(
+        state["params"], pool,
+        on_chip(jax.ShapeDtypeStruct((), jnp.uint32))).as_text()
+    assert step.startswith("module @jit_host_step ")
+    assert pruned.startswith("module @jit_pruned ")
+    assert "slstm_fwd" in step and "slstm_bwd" in step
+    assert "slstm_fwd" in pruned and "slstm_bwd" not in pruned
+
+
+def test_xlstm_sharded_step_runs_the_slstm_kernel_per_shard(v5e_2x2,
+                                                           monkeypatch):
+    """xlstm-350m's update on the four described chips as a (data=2,
+    model=2) mesh, as ``chip_smoke.py --chips 4`` runs it, compiles with
+    the sLSTM kernels, each on its data shard's 2 of the 4 rows. A Mosaic
+    call cannot be partitioned by XLA; unwrapped it fails to compile."""
+    from repro.api import Experiment
+    from repro.api.config import build_run
+    from repro.core.is_train import train_state_init
+    from repro.distributed import sharding as shd
+    from repro.kernels.ce_score import ops as ce_ops
+    from repro.kernels.fused_presample import ops as fp_ops
+    from repro.kernels.slstm import ops as slstm_ops
+    from repro.kernels.topk_keys import ops as tk_ops
+    from repro.launch.mesh import build_mesh
+    from repro.models import xlstm
+    for mod in (ce_ops, fp_ops, slstm_ops, tk_ops, xlstm):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    # the jitted step itself, and the chip's compiler options
+    monkeypatch.setattr(shd, "under_mesh", lambda fn, mesh: fn)
+    monkeypatch.setattr(shd, "compiler_options", lambda mesh: {
+        "xla_tpu_enable_async_collective_fusion_fuse_all_gather": False})
+    mesh = build_mesh((2, 2), ("data", "model"), devices=v5e_2x2.devices)
+    b = 4
+    exp = Experiment(build_run(arch="xlstm-350m", preset="prod", overrides={
+        "shape.seq_len": S, "shape.global_batch": b,
+        "imp.presample_ratio": 3, "obs.sink": "none"}), mesh=mesh)
+    state = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(lambda k: train_state_init(exp.lm, exp.opt, k),
+                       jax.random.PRNGKey(0)), exp.state_shardings)
+    dp = jax.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
+    batch = {k: jax.ShapeDtypeStruct((b, S), jnp.int32, sharding=dp)
+             for k in ("tokens", "labels")}
+    batch["weights"] = jax.ShapeDtypeStruct((b,), jnp.float32, sharding=dp)
+    args = (state, batch) + ((jax.ShapeDtypeStruct(
+        (), jnp.float32, sharding=jax.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())),)
+        if exp.step_is_flagged else ())
+    with jax.set_mesh(mesh):
+        text = exp.step_fn.lower(*args).compile().as_text()
+    calls = [line.split(" = ", 1) for line in text.splitlines()
+             if "tpu_custom_call" in line and "slstm" in line.split(" = ")[0]]
+    assert {n.strip().split(".")[0].lstrip("%") for n, _ in calls} == {
+        "slstm_fwd", "slstm_bwd"}
+    # each call's hs (fwd) or dg (bwd) holds one shard's rows
+    assert all(out.startswith(f"(f32[{b // 2},{S},") for _, out in calls)
