@@ -8,7 +8,11 @@ survival pruning, host selection and the donated train step. The
 benchmark gives it three things: the configuration (``configs/``), the
 traffic (``workloads/``, made into rows by ``traffic.Rows`` and passed as
 the run's data source) and the initial weights (``reference.make_params``,
-so that the reference starts from the same ones).
+so that the reference starts from the same ones). A cell of several chips
+has its (data, model) mesh in the configuration's ``mesh`` key; the
+program is built on it as its launcher builds it for ``--mesh``, the
+weights are made on the program's own shardings, and the reference is
+laid out over the same chips (``reference.Placement``).
 
 A run:
 
@@ -69,8 +73,23 @@ def load_cell(workload, bench_path=None):
         raise SystemExit(f"unknown workload {workload!r}")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(ROOT.parent.parent / conf["file"])
+    mesh_shape(cell, cfg)
     return cell, cfg, traffic.load(cell["traffic"]), \
         load_json(ROOT / "limits" / f"{workload}.json")
+
+
+def mesh_shape(cell, cfg):
+    """The cell's (data, model) mesh from its configuration's ``mesh``
+    key; None for a one-chip cell or a configuration without one. A mesh
+    of another number of chips than the cell's is an error."""
+    mesh = cfg.get("mesh")
+    if mesh is None:
+        return None
+    shape = int(mesh["data"]), int(mesh["model"])
+    if shape[0] * shape[1] != int(cell["chips"]):
+        raise SystemExit(f"{cell['name']}: the mesh {mesh} is not of the "
+                         f"cell's {cell['chips']} chip(s)")
+    return None if cell["chips"] == 1 else shape
 
 
 def model_config(cfg):
@@ -123,9 +142,10 @@ class WindowHook:
     ends it."""
 
     def __init__(self, cfg, seed, seconds, *, tracer=None, b1=0.9,
-                 clip=1.0):
+                 clip=1.0, param_shardings=None):
         from repro import obs
         self.cfg, self.seed = cfg, seed
+        self.param_shardings = param_shardings
         self.seconds = float(seconds)
         self.tracer = tracer
         self.b1, self.clip = b1, clip
@@ -190,7 +210,8 @@ class WindowHook:
                     st["opt"]["m"]["embed"]) * unclip ** 2
                 self.marks["step0_read"] = time.perf_counter()
             if step == CHECK_STEPS - 1:
-                p0 = reference.make_params(self.cfg, self.seed)
+                p0 = reference.make_params(self.cfg, self.seed,
+                                           self.param_shardings)
                 self.update_norms = reference.diff_norms(
                     st["opt"]["master"], p0)
                 del p0
@@ -254,9 +275,11 @@ class PoolRecorder:
 # ---------------------------------------------------------------------------
 # a run
 # ---------------------------------------------------------------------------
-def build(cfg, tr, seed, marks=None):
+def build(cfg, tr, seed, marks=None, mesh=None, devices=None):
     """The experiment of one run, with its data source and weights.
-    ``marks`` (a dict) gets the clock at the end of each phase."""
+    ``marks`` (a dict) gets the clock at the end of each phase. ``mesh``
+    (data, model) builds the program on those ``devices`` as its
+    launcher's ``--mesh`` does."""
     marks = {} if marks is None else marks
     from repro.api.experiment import Experiment
     from repro.core.is_train import train_state_init
@@ -264,15 +287,28 @@ def build(cfg, tr, seed, marks=None):
     marks["program_imported"] = time.perf_counter()
     run = run_config(cfg, tr, seed)
     rows = traffic.Rows(tr, cfg["vocab_size"], seed)
-    exp = Experiment(run, source=make_source(rows))
+    if mesh is not None:
+        from repro.launch.mesh import (build_mesh, choose_microbatches,
+                                       data_parallel_size)
+        mesh = build_mesh(mesh, ("data", "model"), devices=devices)
+        run = dataclasses.replace(run, microbatches=choose_microbatches(
+            run.model, data_parallel_size(mesh), run.shape.global_batch))
+    exp = Experiment(run, source=make_source(rows), mesh=mesh)
     marks["experiment"] = time.perf_counter()
     check_layout(exp, cfg)
     marks["layout_checked"] = time.perf_counter()
 
     def init_state():
-        params = reference.make_params(cfg, seed)
-        return train_state_init(exp.lm, exp.opt, None, params=params), \
-            PipelineState()
+        sh = exp.state_shardings
+        if sh is None:
+            params = reference.make_params(cfg, seed)
+            return train_state_init(exp.lm, exp.opt, None, params=params), \
+                PipelineState()
+        params = reference.make_params(cfg, seed, sh["params"])
+        init = jax.jit(lambda p: train_state_init(exp.lm, exp.opt, None,
+                                                  params=p),
+                       out_shardings=sh, donate_argnums=0)
+        return init(params), PipelineState()
     exp.init_state = init_state
     return exp, rows
 
@@ -281,7 +317,7 @@ def check_layout(exp, cfg):
     """The benchmark's weights must have the program's tree, shapes and
     dtypes, leaf for leaf."""
     want = jax.eval_shape(exp.lm.init, jax.random.PRNGKey(0))
-    have = jax.eval_shape(lambda: reference.make_params(cfg, 0))
+    have = reference.param_shapes(cfg)
     fw = jax.tree_util.tree_flatten_with_path(want)[0]
     fh = jax.tree_util.tree_flatten_with_path(have)[0]
     a = {jax.tree_util.keystr(p): (x.shape, str(x.dtype)) for p, x in fw}
@@ -295,8 +331,10 @@ def check_layout(exp, cfg):
 def train(exp, cfg, seed, seconds, *, tracer=None, fault=None):
     """Set-up steps + window through ``Experiment.fit``. Returns the hook
     (readings, window) and the recorder of the checked pools."""
+    sh = exp.state_shardings
     hook = WindowHook(cfg, seed, seconds, tracer=tracer, b1=exp.run.optim.b1,
-                      clip=exp.run.optim.grad_clip)
+                      clip=exp.run.optim.grad_clip,
+                      param_shardings=None if sh is None else sh["params"])
     rec = PoolRecorder(exp.sampler) \
         if hasattr(exp.sampler, "begin_finalize") else None
     if fault is not None:
@@ -325,17 +363,20 @@ def program_readings(hook, rec):
             "pools": rec.read() if rec is not None else {}}
 
 
-def probe_loss(params, cfg, rows):
+def probe_loss(params, cfg, rows, place=None):
     """Mean CE of the held-out probe rows under the given (trained)
-    weights, by the float32 reference."""
+    weights, by the float32 reference (laid out by ``place``, if any)."""
     data = rows.gather(rows.probe_ids())
     params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
-    ce, _ = reference.pool_stats(params, data["tokens"], data["labels"], cfg)
+    if place is not None:
+        params = jax.device_put(params, place.tree(params))
+    ce, _ = reference.pool_stats(params, data["tokens"], data["labels"], cfg,
+                                 place=place)
     return float(np.mean(ce))
 
 
 def reference_readings(cfg, tr, seed, rows, plans, limits, mode="f32",
-                       half_batch=False):
+                       half_batch=False, place=None):
     """The reference replays the checked steps from the same weights:
     scores each step's pool with the weights that step's pool was scored
     with (one step stale, as the program's loop dispatches it), selects,
@@ -346,13 +387,18 @@ def reference_readings(cfg, tr, seed, rows, plans, limits, mode="f32",
 
     ``mode="fp8"`` is the control (``numerics``); ``half_batch`` plants
     a fault: each step trains on the first half of its rows only, the
-    mean taken over them."""
+    mean taken over them. ``place`` (``reference.Placement``) lays the
+    weights, moments, gradients and rows over a cell's chips."""
     o = tr["optim"]
     opt = dict(lr=o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
-               wd=o["weight_decay"], clip=o["grad_clip"])
+               wd=o["weight_decay"], clip=o["grad_clip"], place=place)
     b = int(tr["global_batch"])
-    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                    reference.make_params(cfg, seed))
+    where = None if place is None else place.tree(reference.param_shapes(cfg))
+
+    def initial():
+        return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                      reference.make_params(cfg, seed, where))
+    params = initial()
     m = jax.tree_util.tree_map(jnp.zeros_like, params)
     v = jax.tree_util.tree_map(jnp.zeros_like, params)
     out = {"losses": [], "gnorms": [], "pools": {}, "grad_norms": None,
@@ -363,7 +409,7 @@ def reference_readings(cfg, tr, seed, rows, plans, limits, mode="f32",
         ids = traffic.pool_ids(tr, step)
         data = rows.gather(ids)
         ce, sc = reference.pool_stats(weights, data["tokens"],
-                                      data["labels"], cfg, mode)
+                                      data["labels"], cfg, mode, place)
         return {"gids": ids, "loss": ce, "scores": sc, "data": data}
 
     # the program's loop scores pool k+1 with the weights before update k
@@ -391,7 +437,8 @@ def reference_readings(cfg, tr, seed, rows, plans, limits, mode="f32",
         if half_batch:
             tok, lab, w = tok[:b // 2], lab[:b // 2], w[:b // 2]
         loss, grad, rs = reference.loss_and_grad(params, tok, lab, w, cfg,
-                                                 mode, row_scores=True)
+                                                 mode, row_scores=True,
+                                                 place=place)
         out["row_scores"].append(rs)
         if step == 0:
             # the rows' own tokens, and the raw gradient's embedding rows
@@ -408,22 +455,27 @@ def reference_readings(cfg, tr, seed, rows, plans, limits, mode="f32",
             out["grad_sketch"] = reference.sketch(g)
         del g
         out["losses"].append(loss)
-    p0 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                reference.make_params(cfg, seed))
-    out["update_norms"] = reference.diff_norms(params, p0)
+    out["update_norms"] = reference.diff_norms(params, initial())
     return out
 
 
-def device_info():
-    devices = jax.devices()
-    peak = 0
+def peak_bytes(devices):
+    """Each device's peak memory in use, where the backend reports it."""
+    out = []
     for d in devices:
         try:
-            peak = max(peak, int(d.memory_stats()["peak_bytes_in_use"]))
+            out.append(int(d.memory_stats()["peak_bytes_in_use"]))
         except Exception:
-            pass
+            out.append(0)
+    return out
+
+
+def device_info(devices):
+    """The host's devices as JAX reports them, and the peak memory of the
+    fullest of the cell's ``devices``."""
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
-            "count": len(devices), "memory_peak_bytes": peak}
+            "count": len(jax.devices()),
+            "memory_peak_bytes": max(peak_bytes(devices))}
 
 
 def run(workload, seed, seconds, *, trace=False, fault=None, cell=None,
@@ -441,7 +493,10 @@ def run(workload, seed, seconds, *, trace=False, fault=None, cell=None,
     if trace:
         from chipbench import tracefile
         tracer = tracefile.Tracer(ROOT / ".out" / "trace" / workload)
-    exp, rows = build(cfg, tr, seed, marks)
+    # the cell's chips: the host's first ``chips`` devices
+    shape, devices = mesh_shape(cell_e, cfg), jax.devices()[:cell_e["chips"]]
+    place = None if shape is None else reference.Placement(devices, *shape)
+    exp, rows = build(cfg, tr, seed, marks, mesh=shape, devices=devices)
     if trace:
         from chipbench import layers
         layers.annotate_experiment(exp)
@@ -452,16 +507,18 @@ def run(workload, seed, seconds, *, trace=False, fault=None, cell=None,
         f"{k} {v - t_start:.3f}" for k, v in marks.items()))
     window_s = hook.t1 - hook.t0
     b, T = int(tr["global_batch"]), int(tr["seq_len"])
-    dev = device_info()
+    dev = device_info(devices)
+    log(f"peak bytes in use by chip: {peak_bytes(devices)}")
     prog = program_readings(hook, rec)
     # the probe loss reads the trained weights; then the program's state
-    # goes, so that the reference has the chip to itself
-    pl = probe_loss(hook.loop.state["params"], cfg, rows)
+    # goes, so that the reference has the chips to itself
+    pl = probe_loss(hook.loop.state["params"], cfg, rows, place)
     name = exp.run.model.name
     hook.loop = None
     del exp, rec
     gc.collect()
-    ref = reference_readings(cfg, tr, seed, rows, prog["plans"], limits)
+    ref = reference_readings(cfg, tr, seed, rows, prog["plans"], limits,
+                             place=place)
     checks = compare.checks(prog, ref, tr, seed, limits,
                             gate_shut=hook.gate_shut)
     for k, v in compare.gaps(prog, ref, tr, seed).items():
@@ -469,8 +526,10 @@ def run(workload, seed, seconds, *, trace=False, fault=None, cell=None,
     correct = all(c["ok"] for c in checks)
     if trace:
         metrics, breakdown, summary = layers.per_layer(
-            workload, cfg, tr, hook, tracer, dev["kind"], layers.load_bench())
+            workload, cfg, tr, hook, tracer, dev["kind"], layers.load_bench(),
+            devices=[d.id for d in devices])
         dev.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
+        log(f"trace planes read: {summary['planes']}")
     else:
         metrics = {
             "tokens_per_s": {"value": b * T * hook.window_steps / window_s,

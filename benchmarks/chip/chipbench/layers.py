@@ -5,15 +5,18 @@ Each per-layer metric of ``BENCHMARK.json`` has a reader of its own,
 reader found nothing to read in this cell) leaves the metric out of the
 result line. ``ctx`` carries what a reader may use:
 
-``ctx.trace``     ``tracefile.reduce`` of the traced part of the window:
-                  ``window_s``, ``busy_s``, device seconds by program
-                  (``modules``), by operation (``ops``) and by program and
-                  opcode (``by_module``)
+``ctx.trace``     ``tracefile.reduce`` of the traced part of the window,
+                  over the cell's chips: ``window_s``, ``busy_s``, device
+                  seconds by program (``modules``), by operation (``ops``)
+                  and by program and opcode (``by_module``), each a mean
+                  over the chips
 ``ctx.traced_steps``  training steps in the traced part
 ``ctx.steps``     training steps completed in the whole window
 ``ctx.window_s``  the whole window's length by the host clock
 ``ctx.hook``      the run's ``harness.WindowHook`` (per-step gauges,
                   span sums read at the window's edges)
+``ctx.chips``     the cell's chips; ``ctx.devices`` their ids, whose
+                  planes of the trace are read
 ``ctx.cfg``, ``ctx.traffic``, ``ctx.device_kind``
 """
 from __future__ import annotations
@@ -70,13 +73,15 @@ def metrics_of(workload, bench):
     return out
 
 
-def per_layer(workload, cfg, tr, hook, tracer, device_kind, bench):
-    summary = tracefile.reduce(tracefile.load(tracer.path), ANNOTATIONS)
+def per_layer(workload, cfg, tr, hook, tracer, device_kind, bench, *,
+              devices):
+    summary = tracefile.reduce(tracefile.load(tracer.path), ANNOTATIONS,
+                               devices=devices)
     ctx = types.SimpleNamespace(
         trace=summary, steps=hook.window_steps,
         traced_steps=hook.traced_steps,
         window_s=hook.t1 - hook.t0, hook=hook, cfg=cfg, traffic=tr,
-        device_kind=device_kind)
+        device_kind=device_kind, chips=len(devices), devices=tuple(devices))
     out = {}
     for m in metrics_of(workload, bench):
         v = reader(m["name"]).read(ctx)

@@ -12,6 +12,10 @@ dtypes the program trains (the harness checks the layout against the
 program's own), so the program and the reference start from one set of
 weights that neither side's code made for the other. Everything else
 here is float32 and imports nothing of the program.
+
+On a cell of several chips a ``Placement`` lays the reference's arrays
+over them; the mathematics stay as they are, and without one (a one-chip
+cell) every call runs as it always has.
 """
 from __future__ import annotations
 
@@ -21,6 +25,7 @@ import importlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from chipbench.numerics import Numerics
 from chipbench.refs.common import F32, ONES, dense, rmsnorm
@@ -37,6 +42,59 @@ def seed_key(seed):
     seed = int(seed)
     key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
     return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
+
+
+# ---------------------------------------------------------------------------
+# placement over a cell's chips
+# ---------------------------------------------------------------------------
+class Placement:
+    """Where the reference's arrays lie on a cell of several chips, a
+    (data, model) grid of ``devices``: every leaf of the weights, the
+    optimizer's moments and the gradient split over all the chips along
+    its largest axis that their number divides (replicated where none
+    does); the rows of a call over the data axis."""
+
+    def __init__(self, devices, data, model):
+        self.mesh = Mesh(np.asarray(devices).reshape(data, model),
+                         ("data", "model"))
+
+    # a static argument of the reference's jitted calls: equal placements
+    # share their compiled programs
+    def __eq__(self, other):
+        return isinstance(other, Placement) and self.mesh == other.mesh
+
+    def __hash__(self):
+        return hash(self.mesh)
+
+    def leaf(self, shape):
+        n = self.mesh.size
+        axes = [i for i, s in enumerate(shape) if s % n == 0]
+        if not axes:
+            return NamedSharding(self.mesh, P())
+        i = max(axes, key=lambda a: shape[a])
+        return NamedSharding(self.mesh, P(*[("data", "model") if a == i
+                                            else None for a in range(i + 1)]))
+
+    def tree(self, tree):
+        """The shardings of a tree of arrays (or of their shapes)."""
+        return jax.tree_util.tree_map(lambda x: self.leaf(x.shape), tree)
+
+    def constrain(self, tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.lax.with_sharding_constraint(x, self.leaf(x.shape)),
+            tree)
+
+    def rows(self, x):
+        """A block of rows on the chips, split over the data axis where
+        the data axis divides them."""
+        x = jnp.asarray(x)
+        split = x.shape[0] % self.mesh.shape["data"] == 0
+        return jax.device_put(x, NamedSharding(
+            self.mesh, P("data") if split else P()))
+
+
+def _rows(x, place):
+    return jnp.asarray(x) if place is None else place.rows(x)
 
 
 # ---------------------------------------------------------------------------
@@ -88,7 +146,6 @@ def _leaf(key, spec, model_dtype):
     return v.astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_key",))
 def _build(key, *, cfg_key):
     cfg = _CFGS[cfg_key]
     leaves, tree = jax.tree_util.tree_flatten(param_spec(cfg),
@@ -98,10 +155,20 @@ def _build(key, *, cfg_key):
         tree, [_leaf(k, s, cfg["dtype"]) for k, s in zip(keys, leaves)])
 
 
-def make_params(cfg, seed):
+_build_jit = jax.jit(_build, static_argnames=("cfg_key",))
+
+
+def make_params(cfg, seed, shardings=None):
     """The weights of one run, on the device, from the seed: one jitted
-    call, in the dtypes the program trains them in."""
-    return _build(seed_key(seed), cfg_key=_register(cfg))
+    call, in the dtypes the program trains them in; with ``shardings``
+    (a tree of them), each leaf made where it lies."""
+    build = _build_jit if shardings is None else jax.jit(
+        _build, static_argnames=("cfg_key",), out_shardings=shardings)
+    return build(seed_key(seed), cfg_key=_register(cfg))
+
+
+def param_shapes(cfg):
+    return jax.eval_shape(lambda: make_params(cfg, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -162,10 +229,12 @@ def _loss(params, tokens, labels, weights, cfg, num):
     return jnp.sum(ce * weights), jnp.sqrt(jnp.maximum(g2, 1e-20))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_key", "mode"))
-def _loss_grad(params, tokens, labels, weights, *, cfg_key, mode):
-    return jax.value_and_grad(_loss, has_aux=True)(
+@functools.partial(jax.jit, static_argnames=("cfg_key", "mode", "place"))
+def _loss_grad(params, tokens, labels, weights, *, cfg_key, mode,
+               place=None):
+    out, grad = jax.value_and_grad(_loss, has_aux=True)(
         params, tokens, labels, weights, _CFGS[cfg_key], Numerics(mode))
+    return out, grad if place is None else place.constrain(grad)
 
 
 _CFGS = {}
@@ -177,13 +246,13 @@ def _register(cfg):
     return key
 
 
-def pool_stats(params, tokens, labels, cfg, mode="f32"):
+def pool_stats(params, tokens, labels, cfg, mode="f32", place=None):
     """Per-row (mean CE, Ĝ) of a pool, ROW_BLOCK rows per call; numpy."""
     key = _register(cfg)
     ce, sc = [], []
     for i in range(0, tokens.shape[0], ROW_BLOCK):
-        c, s = _pool_stats(params, jnp.asarray(tokens[i:i + ROW_BLOCK]),
-                           jnp.asarray(labels[i:i + ROW_BLOCK]),
+        c, s = _pool_stats(params, _rows(tokens[i:i + ROW_BLOCK], place),
+                           _rows(labels[i:i + ROW_BLOCK], place),
                            cfg_key=key, mode=mode)
         ce.append(np.asarray(c, np.float64))
         sc.append(np.asarray(s, np.float64))
@@ -191,17 +260,17 @@ def pool_stats(params, tokens, labels, cfg, mode="f32"):
 
 
 def loss_and_grad(params, tokens, labels, weights, cfg, mode="f32",
-                  row_scores=False):
+                  row_scores=False, place=None):
     """The step's loss mean_i(w_i·CE_i) and its gradient (f32 tree); with
     ``row_scores``, also each row's Ĝ (numpy)."""
     key = _register(cfg)
     n = tokens.shape[0]
     loss, grad, scores = 0.0, None, []
     for i in range(0, n, ROW_BLOCK):
-        w = jnp.asarray(np.asarray(weights[i:i + ROW_BLOCK], np.float32) / n)
-        (l, sc), g = _loss_grad(params, jnp.asarray(tokens[i:i + ROW_BLOCK]),
-                                jnp.asarray(labels[i:i + ROW_BLOCK]), w,
-                                cfg_key=key, mode=mode)
+        w = _rows(np.asarray(weights[i:i + ROW_BLOCK], np.float32) / n, place)
+        (l, sc), g = _loss_grad(params, _rows(tokens[i:i + ROW_BLOCK], place),
+                                _rows(labels[i:i + ROW_BLOCK], place), w,
+                                cfg_key=key, mode=mode, place=place)
         loss += float(l)
         scores.append(np.asarray(sc, np.float64))
         grad = g if grad is None else jax.tree_util.tree_map(jnp.add, grad, g)
@@ -214,11 +283,14 @@ def loss_and_grad(params, tokens, labels, weights, cfg, mode="f32",
 # AdamW, as the job states it
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("lr", "b1", "b2", "eps", "wd",
-                                             "clip"), donate_argnums=(0, 1, 2))
-def adamw_step(params, m, v, grads, step, *, lr, b1, b2, eps, wd, clip):
+                                             "clip", "place"),
+                   donate_argnums=(0, 1, 2))
+def adamw_step(params, m, v, grads, step, *, lr, b1, b2, eps, wd, clip,
+               place=None):
     """Global-norm clip, then decoupled-weight-decay Adam with bias
     correction. Returns (params, m, v, the clipped gradient, the global
-    gradient norm before the clip)."""
+    gradient norm before the clip); with ``place``, the trees laid out by
+    it."""
     gn = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads)))
     scale = jnp.minimum(1.0, clip / jnp.maximum(gn, 1e-12)) if clip > 0 \
         else 1.0
@@ -230,6 +302,8 @@ def adamw_step(params, m, v, grads, step, *, lr, b1, b2, eps, wd, clip):
     params = jax.tree_util.tree_map(
         lambda p, a, s: p - lr * ((a / c1) / (jnp.sqrt(s / c2) + eps)
                                   + wd * p), params, m, v)
+    if place is not None:
+        params, m, v, g = place.constrain((params, m, v, g))
     return params, m, v, g, gn
 
 

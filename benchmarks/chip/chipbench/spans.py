@@ -52,19 +52,24 @@ def span_matcher(patterns=None):
     return lambda name: name in exact or any(r.fullmatch(name) for r in rx)
 
 
-def load(path):
+def load(path, devices=None):
     """A trace as plain lists, times in ns: {"window": (s, e), "devices":
-    [[(s, e) of each operation]], "lines": [[(name, s, e)] of each host
-    thread: program spans and step markers only]}."""
+    [[(s, e) of each operation]] of the chips ``devices`` (ids; every
+    chip where None), "lines": [[(name, s, e)] of each host thread:
+    program spans and step markers only]}."""
     from jax.profiler import ProfileData
     is_span = span_matcher()
-    devices, lines, window = [], [], None
+    devices = None if devices is None else set(devices)
+    out, lines, window = [], [], None
     for plane in ProfileData.from_file(str(path)).planes:
         if tracefile.DEVICE_PLANE.match(plane.name):
+            if devices is not None \
+                    and tracefile.device_id(plane.name) not in devices:
+                continue
             for line in plane.lines:
                 if line.name == tracefile.OPS_LINE:
-                    devices.append([(float(e.start_ns), float(e.end_ns))
-                                    for e in line.events])
+                    out.append([(float(e.start_ns), float(e.end_ns))
+                                for e in line.events])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 kept = []
@@ -75,7 +80,7 @@ def load(path):
                         kept.append((e.name, float(e.start_ns),
                                      float(e.end_ns)))
                 lines.append(kept)
-    return {"window": window, "devices": devices, "lines": lines}
+    return {"window": window, "devices": out, "lines": lines}
 
 
 def _owners(tr):
@@ -147,7 +152,8 @@ def traced(ctx):
     if not hasattr(ctx, "spans_trace"):
         tracer = getattr(ctx.hook, "tracer", None)
         path = getattr(tracer, "path", None)
-        tr = load(path) if path is not None else None
+        tr = load(path, getattr(ctx, "devices", None)) \
+            if path is not None else None
         if tr is not None and (tr["window"] is None or not found(
                 tr, span_matcher())):
             tr = None

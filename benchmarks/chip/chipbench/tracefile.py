@@ -9,6 +9,10 @@ marked with a host annotation, ``WINDOW``. ``reduce`` reads the
 * device planes are those named ``/device:TPU:<n>``; their line named
   ``XLA Ops`` holds one event per operation run on the chip, the line
   ``XLA Modules`` one event per execution of a compiled program;
+* only the planes of the chips a cell ran on are read (``devices``, the
+  ``<n>`` of their names; every plane where none are given), and each
+  time below is a mean over them: an idle chip of the host that the cell
+  does not use counts for nothing;
 * busy time is the union of the operation intervals inside the window,
   averaged over the chips; the idle share is 1 − busy / window;
 * program and operation times are the sums of their events' durations
@@ -149,9 +153,22 @@ def _charge(ops, modules, lo, hi):
     return out
 
 
-def reduce(tr, annotations=()):
+def device_id(plane_name):
+    return int(plane_name.rsplit(":", 1)[1])
+
+
+def cell_planes(planes, devices=None):
+    """The device planes of the chips ``devices`` (ids), or all."""
+    if devices is None:
+        return list(planes)
+    devices = set(devices)
+    return [p for p in planes if device_id(p["name"]) in devices]
+
+
+def reduce(tr, annotations=(), devices=None):
     """Window, busy, idle gaps and per-name device times of a loaded
-    trace. ``annotations``: host event names that may explain a gap."""
+    trace, averaged over the planes of ``devices`` (ids; all planes where
+    None). ``annotations``: host event names that may explain a gap."""
     if tr["window"] is None:
         raise ValueError("the trace holds no window annotation")
     lo, hi = tr["window"]
@@ -159,7 +176,8 @@ def reduce(tr, annotations=()):
     busy, ops, modules, calls, gaps = [], {}, {}, {}, {}
     by_module = {}
     ann = [(n, s, e) for _, n, s, e in tr["host"] if n in annotations]
-    for d in tr["devices"]:
+    planes = cell_planes(tr["devices"], devices)
+    for d in planes:
         merged = merge([(s, e) for _, s, e in d["ops"]], lo, hi)
         busy.append(sum(e - s for s, e in merged) * 1e-9)
         for k, v in _sum_by_name(d["ops"], lo, hi).items():
@@ -180,8 +198,9 @@ def reduce(tr, annotations=()):
             cover = [(ae - as_, n) for n, as_, ae in ann if as_ <= mid <= ae]
             what = min(cover)[1] if cover else "host: other"
             gaps[what] = gaps.get(what, 0.0) + (e - s) * 1e-9
-    n = max(len(tr["devices"]), 1)
+    n = max(len(planes), 1)
     return {
+        "planes": [d["name"] for d in planes],
         "window_s": window_s,
         "busy_s": float(np.mean(busy)) if busy else 0.0,
         "ops": {k: v / n for k, v in ops.items()},

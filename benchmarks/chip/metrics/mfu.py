@@ -1,6 +1,8 @@
-"""mfu: the whole step's share of the chip's bf16 peak — model FLOPs per
-step (``chipbench/flops.py``) times the steps of the traced part of the
-window, over its length and the peak (``chipbench/peaks.json``)."""
+"""mfu: the whole step's share of the peak of the cell's chips — model
+FLOPs per step (``chipbench/flops.py``, every row of the global batch and
+pool) times the steps of the traced part of the window, over its length
+and the chips' bf16 peak, ``ctx.chips`` times one chip's
+(``chipbench/peaks.json``)."""
 from chipbench import flops
 
 
@@ -9,4 +11,4 @@ def read(ctx):
         return None
     rate = flops.step_flops(ctx.cfg, ctx.traffic) * ctx.traced_steps \
         / ctx.trace["window_s"]
-    return 100.0 * rate / flops.peak(ctx.device_kind)
+    return 100.0 * rate / (ctx.chips * flops.peak(ctx.device_kind))

@@ -28,7 +28,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from chipbench import flops, reference  # noqa: E402
+from chipbench import flops, layers, reference  # noqa: E402
 
 CFG = {"d_model": 8, "n_heads": 2, "n_kv_heads": 2, "head_dim": 0,
        "d_ff": 12, "vocab_size": 10,
@@ -60,3 +60,20 @@ def test_peaks_table():
     assert flops.peak("TPU v5 lite", "hbm_bytes_per_s") == 819e9
     with pytest.raises(KeyError):
         flops.peak("cpu")
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mfu_is_per_chip(chips):
+    """The same traced steps over a cell of ``chips`` chips read 1/chips
+    of the one-chip share: the peak is the cell's chips' together."""
+    import types
+    cfg = dict(CFG, segments=[{"pattern": ["mlstm", "slstm"],
+                               "repeats": 3}])
+    tr = {"global_batch": 4, "seq_len": T, "presample_ratio": 3,
+          "scheme": "presample"}
+    ctx = types.SimpleNamespace(traced_steps=2, trace={"window_s": 1e-6},
+                                cfg=cfg, traffic=tr,
+                                device_kind="TPU v5 lite", chips=chips)
+    want = 100.0 * 2 * flops.step_flops(cfg, tr) / 1e-6 / 197e12
+    assert layers.reader("mfu").read(ctx) == pytest.approx(want / chips,
+                                                           rel=1e-12)

@@ -123,7 +123,7 @@ def test_readers_without_the_spans(monkeypatch):
     # benchmark's own annotations, so every reader finds nothing
     bare = dict(tr, lines=[[e for e in line if e[0].startswith("chipbench.")]
                            for line in tr["lines"]])
-    monkeypatch.setattr(spans, "load", lambda path: bare)
+    monkeypatch.setattr(spans, "load", lambda path, devices=None: bare)
     for name in ("idle.unattributed_ms", "idle.loop_sync_ms",
                  "loop.hooks_ms", "select.host_ms"):
         ctx = types.SimpleNamespace(
@@ -194,3 +194,14 @@ def test_traced_small_run_reads_the_program_spans(workload, monkeypatch):
     assert ("select.host_ms" in m) is workload.endswith("is_on")
     assert m["plane.wait_ms"]["value"] >= 0
     assert result["correct"]
+
+
+@pytest.mark.parametrize("devices,planes", [(None, 1), ((0,), 1), ((1,), 0)])
+def test_load_reads_only_the_cells_chips(devices, planes):
+    """The recorded one-chip trace (``testdata/``) holds the plane of chip
+    0 alone: read for chip 0 it keeps that plane's operations, read for
+    chip 1 none."""
+    path = HERE.parent / "testdata" / "v5e_small.xplane.pb"
+    tr = spans.load(path, devices)
+    assert len(tr["devices"]) == planes
+    assert all(tr["devices"])

@@ -54,6 +54,31 @@ def test_reduce_synthetic():
     assert sum(gaps.values()) + r["busy_s"] == pytest.approx(0.1)
 
 
+@pytest.mark.parametrize("busy", [0, 2])
+def test_reduce_reads_only_the_cells_chips(busy):
+    """Four device planes, one busy: reduced over the busy chip alone, as
+    for a one-chip cell on a host of four, the trace reads that chip's
+    own busy time, operations and idle gaps, not a quarter of them."""
+    ann = ("chipbench.step_dispatch", "chipbench.plane_finish")
+    one = synthetic()
+    four = dict(one, devices=[
+        {"name": f"/device:TPU:{i}", "ops": [], "modules": []}
+        for i in range(4)])
+    four["devices"][busy] = dict(one["devices"][0],
+                                 name=f"/device:TPU:{busy}")
+    mine = tracefile.reduce(four, ann, devices=[busy])
+    assert mine.pop("planes") == [f"/device:TPU:{busy}"]
+    alone = tracefile.reduce(one, ann)
+    assert alone.pop("planes") == ["/device:TPU:0"]
+    assert mine == alone
+    assert mine["busy_s"] == pytest.approx(0.070)
+    assert mine["ops"]["dot.2 dot"] == pytest.approx(0.050)
+    assert mine["idle_gaps"]["chipbench.plane_finish"] == pytest.approx(0.020)
+    every = tracefile.reduce(four, ann)
+    assert every["busy_s"] == pytest.approx(0.070 / 4)
+    assert tracefile.reduce(four, ann, devices=range(4)) == every
+
+
 def test_op_name():
     assert tracefile.op_name(
         "%while.684 = (s32[]{:T(128)}, bf16[4,2048]{1,0:T(8,128)(2,1)}) "
