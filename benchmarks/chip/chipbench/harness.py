@@ -37,6 +37,7 @@ import dataclasses
 import gc
 import json
 import time
+import typing
 from pathlib import Path
 
 import jax
@@ -93,14 +94,24 @@ def mesh_shape(cell, cfg):
 
 
 def model_config(cfg):
-    from repro.configs.base import ModelConfig, Segment, SSMConfig
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    kw = {k: v for k, v in cfg.items() if k in fields
-          and k not in ("segments", "ssm")}
+    """The program's ``ModelConfig`` from a configuration file: the file's
+    keys that are fields of it (the others describe the cell), each
+    nested dataclass field (``ssm``, ``moe``, ``mla``, ...) built from its
+    dict by the field's type, and each segment's ``pattern``,
+    ``repeats`` and, where the file gives it, ``dense_ffn``."""
+    from repro.configs.base import ModelConfig, Segment
+    types = typing.get_type_hints(ModelConfig)
+    kw = {}
+    for f in dataclasses.fields(ModelConfig):
+        if f.name not in cfg or f.name == "segments":
+            continue
+        v = cfg[f.name]
+        kw[f.name] = types[f.name](**v) \
+            if dataclasses.is_dataclass(types[f.name]) else v
     return ModelConfig(
-        segments=tuple(Segment(tuple(s["pattern"]), int(s["repeats"]))
-                       for s in cfg["segments"]),
-        ssm=SSMConfig(**cfg["ssm"]), **kw)
+        segments=tuple(Segment(**dict(s, pattern=tuple(s["pattern"]),
+                                      repeats=int(s["repeats"])))
+                       for s in cfg["segments"]), **kw)
 
 
 def run_config(cfg, tr, seed):

@@ -7,6 +7,30 @@ none. The decoder is token embedding → segments (each a pattern of block
 kinds repeated) → RMSNorm → logits against the embedding (tied) or an
 output head.
 
+A kind's module gives ``spec(cfg)``, its weights as ``(shape, dtype,
+init)`` leaves (``refs/common.py``), held once per repeat and stacked on a
+leading repeats axis at ``segments/seg{i}/stacked/p{pi}``;
+``apply(p, x, cfg, num)``, the block on the residual stream ``x`` (rows,
+T, d) in float32 with that repeat's ``p``, returning the new stream; and
+``forward_flops(cfg, T)``, one row's model FLOPs through one invocation.
+It may also declare:
+
+- ``SHARED = True``: its ``spec`` is held once per segment at
+  ``segments/seg{i}/shared``, ``stacked/p{pi}`` is ``{}``, and ``apply``
+  gets the shared weights as ``p`` (one block reused at every repeat);
+- ``shared_spec(cfg)``: weights held once per segment at
+  ``segments/seg{i}/shared`` besides the per-repeat ``spec``; ``apply``
+  then gets them as the keyword ``shared`` next to its repeat's ``p``;
+- ``USES_X0 = True``: ``apply`` gets the keyword ``x0``, the float32
+  output of the embedding lookup (the array the first block sees), the
+  same at every layer.
+
+At most one kind of a segment holds shared weights, as in the program;
+it may stand at several positions of the pattern. A kind that declares
+none of these is called as ``apply(p, x, cfg, num)``. A shared block's
+FLOPs fall on each invocation, so ``forward_flops`` counts its shared and
+per-repeat parts alike.
+
 ``make_params`` draws the weights from the seed, in the tree layout and
 dtypes the program trains (the harness checks the layout against the
 program's own), so the program and the reference start from one set of
@@ -100,6 +124,18 @@ def _rows(x, place):
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
+def shared_spec(mod, cfg):
+    """The spec of the weights ``mod`` holds once per segment; None where
+    it holds none."""
+    whole = getattr(mod, "SHARED", False)
+    if whole and hasattr(mod, "shared_spec"):
+        raise ValueError(f"{mod.__name__} declares both SHARED and "
+                         f"shared_spec")
+    if whole:
+        return mod.spec(cfg)
+    return mod.shared_spec(cfg) if hasattr(mod, "shared_spec") else None
+
+
 def param_spec(cfg):
     """Nested dict of (shape, dtype, init) leaves, in the program's layout:
     per-repeat parameters stacked on a leading axis per pattern position,
@@ -107,12 +143,18 @@ def param_spec(cfg):
     d, V = cfg["d_model"], cfg["vocab_size"]
     out = {"embed": ((V, d), "model", ("normal", 0.02)), "segments": {}}
     for i, seg in enumerate(cfg["segments"]):
-        stacked, s = {}, {}
+        stacked, s, owner = {}, {}, None
         for pi, kind in enumerate(seg["pattern"]):
             mod = block_module(kind)
+            shared = shared_spec(mod, cfg)
+            if shared is not None:
+                if owner not in (None, kind):
+                    raise ValueError(
+                        f"segment {i}: block kinds {owner!r} and {kind!r} "
+                        f"both hold shared weights; at most one may")
+                owner, s["shared"] = kind, shared
             if getattr(mod, "SHARED", False):
                 stacked[f"p{pi}"] = {}
-                s["shared"] = mod.spec(cfg)
             else:
                 stacked[f"p{pi}"] = jax.tree_util.tree_map(
                     lambda l, r=seg["repeats"]: ((r,) + l[0], l[1], l[2]),
@@ -174,17 +216,32 @@ def param_shapes(cfg):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def apply_block(mod, p, x, cfg, num, shared=None, x0=None):
+    """One block on the stream ``x``: ``p`` its repeat's weights, ``shared``
+    its segment's, ``x0`` the embedding lookup; each passed as the kind
+    declares (module docstring)."""
+    if getattr(mod, "SHARED", False):
+        p = shared
+    kw = {}
+    if hasattr(mod, "shared_spec"):
+        kw["shared"] = shared
+    if getattr(mod, "USES_X0", False):
+        kw["x0"] = x0
+    return mod.apply(p, x, cfg, num, **kw)
+
+
 def hidden(params, tokens, cfg, num):
-    x = jnp.take(params["embed"], tokens, axis=0).astype(F32)
+    x0 = x = jnp.take(params["embed"], tokens, axis=0).astype(F32)
     for i, seg in enumerate(cfg["segments"]):
         sp = params["segments"][f"seg{i}"]
         mods = [block_module(k) for k in seg["pattern"]]
 
+        # the shared weights and x0 are closed over, not scanned: one copy
+        # for every repeat, and no residual per repeat under the checkpoint
         def body(x, stacked, sp=sp, mods=mods):
             for pi, mod in enumerate(mods):
-                p = sp["shared"] if getattr(mod, "SHARED", False) \
-                    else stacked[f"p{pi}"]
-                x = mod.apply(p, x, cfg, num)
+                x = apply_block(mod, stacked[f"p{pi}"], x, cfg, num,
+                                sp.get("shared"), x0)
             return x, None
 
         x, _ = jax.lax.scan(jax.checkpoint(body), x, sp["stacked"])

@@ -73,6 +73,22 @@ def test_layout_matches_program(case):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
 
 
+@pytest.mark.parametrize("name", sorted(SMALL))
+def test_layout_matches_program_at_cell_size(name):
+    """The committed configuration as the cell runs it: every repeat, the
+    published widths, the served dtype; shapes only."""
+    from repro.models.lm import LM
+    cfg = json.loads((HERE.parent / "configs" / f"{name}.json").read_text())
+    want = jax.eval_shape(LM(harness.model_config(cfg)).init,
+                          jax.random.PRNGKey(0))
+    have = reference.param_shapes(cfg)
+    assert jax.tree_util.tree_structure(want) == \
+        jax.tree_util.tree_structure(have)
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(have)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
 def test_loss_and_gradient_match_program(case):
     cfg, lm, params = case
     data = rows(cfg, B)
