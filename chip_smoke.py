@@ -50,6 +50,10 @@ KEY_RTOL = 1e-5          # race keys, kernel vs oracle (same hash, f32 tail)
 # recurrent products' operands to bf16, and each is ~5e-3 off exact f32
 # products in the gradients; the kernel sits ~6e-4 from the scan
 SLSTM_RTOL = 2e-3
+# mLSTM GLA kernels vs chunked_gla, relative L2 per output: both take the
+# products' operands in bf16; the reference also rounds the normaliser
+# and the gated value to bf16, the kernel keeps them in f32
+MLSTM_RTOL = 2e-2
 # four chips: the sharded step rounds bf16 partial sums that one device
 # accumulates in f32 (gradients and updates are held to a yardstick
 # measured in the run, see sharded_vs_single)
@@ -215,6 +219,7 @@ def kernel_parity():
                  f"{err_k:.3e} (tol {KEY_RTOL:g}): {'PASS' if ok else 'FAIL'}")
     check(ok, lines[-1])
     lines.append(slstm_parity())
+    lines.append(mlstm_gla_parity())
     return lines
 
 
@@ -251,6 +256,45 @@ def slstm_parity():
             f"b={b} s={s} d={d} heads={nh}: rel L2 err "
             + ", ".join(f"{k} {e:.3e}" for k, e in errs.items())
             + f" (tol {SLSTM_RTOL:g}): {'PASS' if ok else 'FAIL'}")
+    check(ok, line)
+    return line
+
+
+def mlstm_gla_parity():
+    """The mLSTM's GLA kernels (``mlstm_gla``) against ``chunked_gla``
+    (``mlstm_gla_ref``) at xlstm-350m's widths over the trained rows, q, k
+    and v in bf16 as the model gives them: y, the normaliser, and one VJP
+    (q, k, v, input gate, log forget gate). Returns the printed line;
+    raises on a miss."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.mlstm_gla.ops import mlstm_gla
+    from repro.kernels.mlstm_gla.ref import mlstm_gla_ref
+
+    b, s, nh, hd, chunk = BATCH, SEQ, 4, 512, 256
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 6)
+    qkv = [(jax.random.normal(kk, (b, s, nh, hd)) * sc).astype(jnp.bfloat16)
+           for kk, sc in zip(ks[:3], (hd ** -0.5, hd ** -0.5, 1.0))]
+    i_g = jax.nn.sigmoid(jax.random.normal(ks[3], (b, s, nh)))
+    log_f = jax.nn.log_sigmoid(jax.random.normal(ks[4], (b, s, nh)) + 3.0)
+    ct = jax.random.normal(ks[5], (b, s, nh, hd)).astype(jnp.bfloat16)
+
+    def run(op):
+        (y, n), vjp = jax.vjp(lambda *a: op(*a, chunk), *qkv, i_g, log_f)
+        return (y, n), vjp((ct, jnp.ones_like(n)))
+    got = jax.jit(lambda: run(mlstm_gla))()
+    want = jax.jit(lambda: run(mlstm_gla_ref))()
+    f32 = lambda x: x.astype(jnp.float32)
+    names = ["y", "n", "dq", "dk", "dv", "di", "dlog_f"]
+    errs = {k: float(jnp.linalg.norm(f32(x) - f32(y))
+                     / jnp.linalg.norm(f32(y)))
+            for k, x, y in zip(names, jax.tree.leaves(got),
+                               jax.tree.leaves(want))}
+    ok = all(e <= MLSTM_RTOL for e in errs.values())
+    line = (f"parity mlstm_gla (mlstm_gla vs mlstm_gla_ref, forward and VJP) "
+            f"b={b} s={s} heads={nh}x{hd} chunk={chunk}: rel L2 err "
+            + ", ".join(f"{k} {e:.3e}" for k, e in errs.items())
+            + f" (tol {MLSTM_RTOL:g}): {'PASS' if ok else 'FAIL'}")
     check(ok, line)
     return line
 

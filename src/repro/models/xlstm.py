@@ -4,9 +4,10 @@
 The mLSTM recurrence  C_t = f_t C_{t-1} + i_t v_t k_t^T,  n_t = f_t n_{t-1}
 + i_t k_t  is expressed through the shared ``chunked_gla`` machinery by
 augmenting the value vector with a constant-one column so the normaliser n
-rides along as the last value channel. Gates use sigmoid activations
-(a stabilised simplification of the paper's exponential gating — see
-DESIGN.md §8).
+rides along as the last value channel; on TPU, without a cache, it runs as
+a Pallas kernel pair instead (``repro.kernels.mlstm_gla``). Gates use
+sigmoid activations (a stabilised simplification of the paper's
+exponential gating — see DESIGN.md §8).
 
 sLSTM keeps per-head scalar state with block-diagonal recurrent weights and
 is inherently sequential: a Pallas kernel over time on TPU
@@ -14,12 +15,14 @@ is inherently sequential: a Pallas kernel over time on TPU
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.mlstm_gla.ref import augment_value, mlstm_gla_ref
 from repro.kernels.slstm.ref import slstm_scan_ref
 from repro.models.common import dense_init, dtype_of, rmsnorm, split_key
 from repro.models.ssm import _mamba_conv, chunked_gla, gla_step
@@ -80,22 +83,25 @@ def apply_mlstm(params, x, *, cfg, cache=None):
     gates = u.astype(jnp.float32) @ params["w_gates"]
     i_g = jax.nn.sigmoid(gates[..., :nh])                    # (b,s,nh)
     log_f = jax.nn.log_sigmoid(gates[..., nh:])
+    chunk = min(cfg.ssm.chunk, s)
 
-    v_aug = jnp.concatenate(
-        [v * i_g[..., None].astype(v.dtype),
-         jnp.broadcast_to(i_g[..., None], (b, s, nh, 1)).astype(v.dtype)], -1)
+    new_H = None
+    if cache is None and _use_gla_kernel(s, chunk, hd):
+        from repro.kernels.mlstm_gla.ops import mlstm_gla
+        y, n = _per_shard(functools.partial(mlstm_gla, chunk=chunk), b,
+                          (_ROWS,) * 5, (_ROWS,) * 2)(q, k, v, i_g, log_f)
+    elif cache is None:
+        y, n = mlstm_gla_ref(q, k, v, i_g, log_f, chunk)
+    else:
+        v_aug = augment_value(v, i_g)
+        if s == 1:
+            y_aug, new_H = gla_step(q, k, v_aug, log_f, cache["H"])
+        else:  # prefill into an existing state
+            y_aug, new_H = chunked_gla(q, k, v_aug, log_f, h0=cache["H"],
+                                       chunk=chunk)
+        y, n = y_aug[..., :hd], y_aug[..., hd]
 
-    if cache is None:
-        y_aug, _ = chunked_gla(q, k, v_aug, log_f, chunk=min(cfg.ssm.chunk, s))
-        new_H = None
-    elif s == 1:
-        y_aug, new_H = gla_step(q, k, v_aug, log_f, cache["H"])
-    else:  # prefill into an existing state
-        y_aug, new_H = chunked_gla(q, k, v_aug, log_f, h0=cache["H"],
-                                   chunk=min(cfg.ssm.chunk, s))
-
-    y, n = y_aug[..., :hd], y_aug[..., hd:]
-    y = y / jnp.maximum(jnp.abs(n), 1.0)
+    y = y / jnp.maximum(jnp.abs(n[..., None]), 1.0)
     y = y.reshape(b, s, d_in).astype(x.dtype)
     y = rmsnorm(params["out_norm"], y, cfg.norm_eps) * jax.nn.silu(z)
     out = y @ params["w_down"]
@@ -115,10 +121,8 @@ def prefill_mlstm_cache(params, x, *, cfg):
     gates = u.astype(jnp.float32) @ params["w_gates"]
     i_g = jax.nn.sigmoid(gates[..., :nh])
     log_f = jax.nn.log_sigmoid(gates[..., nh:])
-    v_aug = jnp.concatenate(
-        [v * i_g[..., None].astype(v.dtype),
-         jnp.broadcast_to(i_g[..., None], (b, s, nh, 1)).astype(v.dtype)], -1)
-    _, H = chunked_gla(q, k, v_aug, log_f, chunk=min(cfg.ssm.chunk, s))
+    _, H = chunked_gla(q, k, augment_value(v, i_g), log_f,
+                       chunk=min(cfg.ssm.chunk, s))
     return {"conv": conv_state, "H": H}
 
 
@@ -159,21 +163,36 @@ def _use_kernel(s, hd):
     return s > 1 and hd % 128 == 0 and _on_tpu()
 
 
-def _per_shard(fn, b):
-    """``fn`` (rows independent, R whole) run on each device's rows of the
-    mesh in context (``jax.set_mesh``): the kernel's custom call has no
-    partitioning rule, and GSPMD would give every device the whole batch.
-    Rows split over the data-parallel axes that divide ``b``; every other
-    axis holds a copy. Without a mesh, ``fn`` itself."""
+def _use_gla_kernel(s, chunk, hd):
+    """The mLSTM's chunked GLA runs as the Pallas kernels on TPU over whole
+    lane-aligned chunks at lane-aligned head widths; a ragged length, and
+    every other backend, keep ``chunked_gla`` (the caller keeps it for a
+    cache, decode and prefill alike)."""
+    return (s > 1 and s % chunk == 0 and chunk % 128 == 0 and hd % 128 == 0
+            and _on_tpu())
+
+
+_ROWS, _WHOLE = "rows", "whole"
+
+
+def _per_shard(fn, b, ins, outs):
+    """``fn`` run on each device's rows of the mesh in context
+    (``jax.set_mesh``): a kernel's custom call has no partitioning rule,
+    and GSPMD would give every device the whole batch. ``ins`` and
+    ``outs`` mirror ``fn``'s arguments and results, each leaf ``_ROWS``
+    (split over the data-parallel axes that divide ``b``) or ``_WHOLE`` (a
+    copy on every device). Without a mesh, ``fn`` itself."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return fn
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     dp = tuple(a for a in ("pod", "data") if a in sizes)
     rows = P(dp) if dp and b % math.prod(sizes[a] for a in dp) == 0 else P()
+    spec = lambda tree: jax.tree.map(
+        lambda leaf: rows if leaf == _ROWS else P(), tree)
     # a pallas_call's outputs carry no varying-axes type to check
-    return jax.shard_map(fn, in_specs=(rows, P(), (rows,) * 3),
-                         out_specs=(rows, (rows,) * 3), check_vma=False)
+    return jax.shard_map(fn, in_specs=spec(ins), out_specs=spec(outs),
+                         check_vma=False)
 
 
 def _slstm_scan(params, g_in, state):
@@ -181,7 +200,9 @@ def _slstm_scan(params, g_in, state):
     if not _use_kernel(g_in.shape[1], r.shape[1]):
         return slstm_scan_ref(g_in, r, state)
     from repro.kernels.slstm.ops import slstm_scan
-    return _per_shard(slstm_scan, g_in.shape[0])(g_in, r, state)
+    return _per_shard(slstm_scan, g_in.shape[0],
+                      (_ROWS, _WHOLE, (_ROWS,) * 3),
+                      (_ROWS, (_ROWS,) * 3))(g_in, r, state)
 
 
 def apply_slstm(params, x, *, cfg, cache=None):

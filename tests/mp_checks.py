@@ -168,6 +168,53 @@ def check_slstm_kernel_sharded():
     print("OK slstm_kernel_sharded")
 
 
+def check_mlstm_gla_kernel_sharded():
+    """The mLSTM block under a (2,2) mesh with the mixing on the GLA
+    kernels (interpret mode) == ``chunked_gla`` on one device, loss and
+    every parameter's gradient; each kernel call sees one data shard's
+    rows."""
+    from repro.configs.base import MLSTM, ModelConfig, Segment
+    from repro.distributed import sharding as shd
+    from repro.kernels.mlstm_gla import ops
+    from repro.models import xlstm
+
+    cfg = ModelConfig(name="mlstm-t", family="ssm", d_model=256, n_heads=4,
+                      n_kv_heads=4, d_ff=0, vocab_size=64,
+                      segments=(Segment((MLSTM,), 1),), dtype="float32")
+    params = xlstm.init_mlstm(jax.random.PRNGKey(0), cfg)
+    s = 2 * cfg.ssm.chunk
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, s, cfg.d_model))
+    y = jax.random.normal(jax.random.PRNGKey(2), (4, s, cfg.d_model))
+
+    def loss(p, x):
+        out, _ = xlstm.apply_mlstm(p, x, cfg=cfg)
+        return jnp.mean((out - y) ** 2)
+
+    grad = jax.jit(jax.value_and_grad(loss))
+    ref_loss, ref_grads = grad(params, x)
+
+    mesh = build_mesh((2, 2), ("data", "model"))
+    xlstm._on_tpu = lambda: True
+    rows = []
+    op = ops.mlstm_gla
+    ops.mlstm_gla = lambda q, *a, **kw: rows.append(q.shape[0]) or op(
+        q, *a, **kw)
+    specs = {"w_up": P(None, "model"), "conv_w": P(None, "model"),
+             "wq": P(None, "model"), "wk": P(None, "model"),
+             "wv": P(None, "model"), "w_gates": P(),
+             "out_norm": {"scale": P()}, "w_down": P("model", None)}
+    with jax.set_mesh(mesh):
+        p = jax.device_put(params, shd.to_named(specs, mesh))
+        xs = jax.device_put(x, jax.NamedSharding(mesh, P("data")))
+        k_loss, k_grads = grad(p, xs)
+    assert rows and set(rows) == {2}, rows
+    np.testing.assert_allclose(float(k_loss), float(ref_loss), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(k_grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    print("OK mlstm_gla_kernel_sharded")
+
+
 def check_compressed_psum():
     from repro.optim.grad_compress import compressed_psum_tree, ef_init
 
